@@ -28,32 +28,9 @@ from pathlib import Path
 from repro import obs
 from repro.bench.schema import (BenchReport, BenchResult, Metric,
                                 next_bench_path, save)
+from repro.launch.compile_cache import enable_compile_cache
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
-
-
-def _enable_jax_compile_cache() -> None:
-    """Persist XLA executables across bench processes.
-
-    The quick benches are compile-dominated on a 1-core CPU runner (the
-    shared robust_smoke evaluator alone costs ~16s of XLA time), so repeat
-    runs load compiled programs from a disk cache instead.  Opt out with
-    ``ROSA_JAX_CACHE=0``; relocate with ``ROSA_JAX_CACHE_DIR``.  Best
-    effort: unsupported jax versions just run uncached.
-    """
-    import os
-    if os.environ.get("ROSA_JAX_CACHE", "1") == "0":
-        return
-    cache_dir = os.environ.get(
-        "ROSA_JAX_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "rosa", "jax"))
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "all")
-    except Exception:
-        pass
 
 
 class SkipBench(Exception):
@@ -650,7 +627,7 @@ def main(argv: list[str] | None = None) -> int:
 
     quick = not args.full
     names = args.only if args.only else list(BENCHES)
-    _enable_jax_compile_cache()
+    enable_compile_cache()
     obs.install_jax_hooks()      # XLA retrace/cache counters per bench
     results = run_benches(
         names, quick,

@@ -33,7 +33,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.core import energy as E
 from repro.core import energy_vec as EV
@@ -149,7 +148,7 @@ def evaluate_grid(workloads: Sequence[Workload],
     onehot[np.arange(len(shapes)), np.array(wl_id)] = 1.0
     spec = EV.EnergySpec.make(mapping=mapping, mode=mode, osa=osa, batch=batch)
 
-    with enable_x64():
+    with jax.enable_x64():
         edp, rel, geo, worst, metric = _grid_eval(
             cand_arrays, layer_arrays, jnp.asarray(onehot, jnp.float64),
             spec, jnp.asarray(lam, jnp.float64))

@@ -14,7 +14,7 @@ evaluates as one vmapped, jitted call:
 Compute mode, dataflow mapping, OSA sizing and bit widths are *static*
 (they select formulas, not values) and ride in an `EnergySpec`; rows, cols,
 tiles and the GEMM dims are traced array data.  Everything runs in float64
-(via `jax.experimental.enable_x64`) so the vectorized path matches the
+(via `jax.enable_x64`) so the vectorized path matches the
 scalar reference to ~1e-15 relative — the DSE parity test pins 1e-6.
 
 Scalar-model invariants preserved here (see energy.layer_energy):

@@ -130,15 +130,15 @@ def profile_layers_fast(layers: Sequence[E.LayerShape],
     alpha collapses to alpha_min, and the hybrid plan reduces to the
     per-layer EDP argmin — the paper's search with the accuracy term muted.
     """
+    import jax
     import numpy as np
-    from jax.experimental import enable_x64
 
     from repro.core import energy_vec as EV
 
     cand = EV.stack_candidates([ope])
     stacked = EV.stack_layers(layers)
     edps = {}
-    with enable_x64():
+    with jax.enable_x64():
         for mp in (Mapping.IS, Mapping.WS):
             spec = EV.EnergySpec.make(mapping=mp, mode=mode, osa=osa,
                                       batch=batch)

@@ -34,17 +34,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.models.module import ParamDef
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across the API rename: newer jax exposes it at the top
-    level with `check_vma`; older releases have
-    jax.experimental.shard_map.shard_map with `check_rep`."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
 # ---------------------------------------------------------------------------
 # Rules tables
 # ---------------------------------------------------------------------------
@@ -190,7 +179,7 @@ def replicated(mesh: Mesh):
 #
 # Continuous-batching decode is embarrassingly parallel over slots: every
 # slot's token depends only on its own cache row.  The serve step therefore
-# runs under a shard_map (shard_map_compat) whose in/out specs shard every
+# runs under a jax.shard_map whose in/out specs shard every
 # state leaf on its slot dimension; these helpers build those specs from
 # the cache's logical-axes tree, so the serve subsystem never hand-indexes
 # leaf ranks.

@@ -4,21 +4,9 @@
 
 
 def on_tpu() -> bool:
-    """Whether the default jax backend is a real TPU (Pallas compiles
-    natively); every kernel wrapper keys interpret-mode fallback off this
-    ONE helper so a future backend rename is a one-line fix."""
+    """Whether the default jax backend is a real TPU, where every Pallas
+    kernel compiles natively.  Every kernel wrapper passes
+    `interpret=not on_tpu()`: interpret mode is the CPU test path only."""
     import jax
     return jax.default_backend() == "tpu"
 
-
-def tpu_compiler_params(**kwargs):
-    """Pallas TPU CompilerParams across the jax rename (TPUCompilerParams
-    in older releases).  Raises a descriptive error if neither exists."""
-    import jax.experimental.pallas.tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise ImportError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; unsupported jax version")
-    return cls(**kwargs)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 from repro.core import mrr
 
 
@@ -90,7 +88,7 @@ def mrr_transfer_pallas(w_target: jax.Array, eps_dac: jax.Array,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((rows, cols), w_target.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(w_target, eps_dac, eps_th)

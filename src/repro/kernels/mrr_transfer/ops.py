@@ -2,7 +2,7 @@
 
 Accepts arbitrary-shape weight tensors; flattens to 2-D, pads to block
 alignment, draws the noise operands from a PRNG key, dispatches to the
-Pallas kernel (interpret mode off-TPU).
+Pallas kernel (interpreted on the CPU, the test path).
 """
 
 from __future__ import annotations

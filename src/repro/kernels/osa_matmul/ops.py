@@ -1,7 +1,7 @@
 """Jitted public wrapper around the OSA matmul kernel.
 
 Handles: quantization-scale plumbing, padding to MXU-aligned block multiples,
-CPU fallback (interpret mode), and default ideal slot gains.
+the interpreter on the CPU (the test path), and default ideal slot gains.
 """
 
 from __future__ import annotations

@@ -41,8 +41,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _plane(qf, t):
     """Signed digit plane t of integer-valued float tensor qf (VMEM-local)."""
@@ -115,7 +113,7 @@ def osa_matmul_pallas(q: jax.Array, w: jax.Array, gains: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

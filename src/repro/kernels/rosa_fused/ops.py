@@ -1,14 +1,20 @@
 """Jitted public wrapper around the fused ROSA megakernel.
 
-Handles everything the tiled kernel cannot do locally, in the order the
-composed `rosa.backends` chain fixes:
+Handles everything the tiled kernel does not do, in the order the composed
+`rosa.backends` chain fixes:
 
+  * the activation operand — conditioned (digital EO path, noisy analog
+    realization, gate blend, mapping-gate superposition) by
+    `ref.condition_x` / `ref.analog_operand`, the composed chain's own
+    functions, and requantized with `core.quant.quantize`, exactly as
+    `osa.osa_matmul_ref` requantizes.  The kernel therefore contracts the
+    very codes the composed chain contracts: no float-order difference
+    between Mosaic and XLA can move a code across its rounding boundary.
+    The (M, K) operand is conditioned once here rather than once per
+    output tile; the O(T*M*K*N) contraction and the (K, N) weight
+    realization stay fused in the kernel.
   * quantization full-scales — global (or per-row) absmax reductions,
-    computed here and streamed in as the (M, 3) scale operand.  The
-    requantization scale of the conditioned activations is obtained from a
-    cheap elementwise pre-pass over x (standard dynamic-quantization
-    practice); the O(T*M*K*N) contraction and both (K, N)/(M, K)
-    realizations stay fused in the kernel.
+    computed here and streamed in as the (M, 1) scale operand.
   * PRNG discipline — the per-layer key splits exactly as `_forward`
     does (mgate/ANALOG: (k_w, k_x); static WS: whole key to the weight
     side; static IS: to the activation side), and each side's Gaussians
@@ -16,14 +22,14 @@ composed `rosa.backends` chain fixes:
     the kernel sees bit-identical noise to the composed path.
   * static variation — `StaticVariation` fields broadcast per orientation
     (core.mrr.expand_lanes) and fold with the noise draws into the three
-    additive chain offsets the kernel consumes.
+    additive chain offsets the kernel consumes for the weights.
   * padding to MXU-aligned block multiples + the unpadded-K bookkeeping
     the kernel needs to mask analog-realized pad lanes.
 
-Static specialization (`realize_x`/`realize_w`) mirrors
-`_analog_operand`'s ideal shortcut: a side with ideal noise, no variation
-and no gate skips the chain entirely, so the ideal fused path matches the
-composed one with zero realization round-trip error.
+Static specialization (`realize_w`) mirrors `_analog_operand`'s ideal
+shortcut: a side with ideal noise, no variation and no gate skips the
+chain entirely, so the ideal fused path matches the composed one with
+zero realization round-trip error.
 """
 
 from __future__ import annotations
@@ -97,14 +103,13 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
     Semantics are those of the composed `rosa.backends._forward` with the
     "ref" contraction backend (the parity tests pin this); `gate`, `mgate`
     and `var` leaves enter as kernel OPERANDS, so gated evaluators sweep
-    them without retracing.  Two contract caveats: (a) the kernel assumes
-    the quantizer's 1e-8 absmax floor never binds (operands whose global
-    absmax is below 1e-8 are a degenerate all-zero edge case); (b) the
-    in-kernel realization chain reorders float ops vs the composed path,
-    so a conditioned activation landing within float noise of a
-    requantization rounding boundary may flip one 8-bit code — each flip
-    moves that row's outputs by at most one requant LSB (the parity tests
-    assert this bound; see tests/test_kernels.py::assert_quantized_parity).
+    them without retracing.  Contract caveat: the kernel assumes the
+    quantizer's 1e-8 absmax floor never binds on the weights (a weight
+    whose global absmax is below 1e-8 is a degenerate all-zero edge case).
+    The activations are requantized by the composed chain's own code, so
+    the kernel contracts the chain's codes; outputs differ from the chain
+    only by float accumulation order and the weight realization's float
+    order (tests/test_kernels.py::assert_quantized_parity).
     """
     if mode is ComputeMode.DIGITAL:
         raise ValueError("DIGITAL layers take the exact digital path; the "
@@ -136,26 +141,24 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
     else:
         k_w, k_x = None, key
 
-    # -- scales --
-    sw = Q.absmax_scale(w)
+    # -- the activation operand, conditioned and requantized exactly as
+    # the composed chain does it (same functions, same k_x -> same draws)
     if analog:
-        sxd = sxa = s2 = Q.absmax_scale(x)
+        x_eff = ref.analog_operand(
+            x, k_x, qcfg=qcfg, p=p, noise=noise, var=var, gate=gate,
+            clean_per_vector=False, noisy_per_vector=False)
+        s2 = Q.absmax_scale(x)
+        xa = x_eff * (1.0 / s2)
     else:
-        sxd = Q.absmax_scale(x, act_per_vector)
-        sxa = Q.absmax_scale(x, True)
-        # requant scale of the CONDITIONED activations: a global reduction
-        # the tiled kernel cannot see — recompute the composed operand
-        # elementwise (ref.condition_x consumes the same k_x, so its noise
-        # draws are the kernel's) and take its absmax
-        x_eff_pre = ref.condition_x(
+        x_eff = ref.condition_x(
             x, k_x, x_active=realize_x, use_mgate=use_mgate, mgate=mgate,
             gate=gate, var=var, qcfg=qcfg, p=p,
             noise=noise if realize_x else mrr.IDEAL,
             act_per_vector=act_per_vector)
-        s2 = Q.absmax_scale(x_eff_pre, act_per_vector)
+        xa, s2 = Q.quantize(x_eff, qcfg, per_vector=act_per_vector)
+    sw = Q.absmax_scale(w)
 
-    # -- noise/variation offsets per realized orientation --
-    x_off = (_offsets(x, k_x, noise, var) if realize_x else None)
+    # -- noise/variation offsets for the weights --
     w_off = (_offsets(w, k_w, noise, mrr.expand_lanes(var, w))
              if realize_w else None)
 
@@ -171,22 +174,14 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
                                 pam_bits=pam_bits), None, jnp.float32)
 
     # -- pad + launch --
-    xp = _pad_to(_pad_to(x, bm, 0), bk, 1)
+    xp = _pad_to(_pad_to(xa, bm, 0), bk, 1)
     wp = _pad_to(_pad_to(w, bk, 0), bn, 1)
-    mp = xp.shape[0]
-
-    def col(s):
-        return jnp.broadcast_to(jnp.asarray(s, jnp.float32), (m, 1)) \
-            if jnp.ndim(s) == 0 else jnp.asarray(s, jnp.float32)
-
-    sx = jnp.concatenate([col(sxd), col(sxa), col(s2)], axis=1)
-    sx = jnp.pad(sx, ((0, mp - m), (0, 0)), constant_values=1.0)
+    s2 = jnp.broadcast_to(jnp.asarray(s2, jnp.float32), (m, 1))
+    s2 = jnp.pad(s2, ((0, xp.shape[0] - m), (0, 0)), constant_values=1.0)
     z = jnp.float32(0.0)
     gg = jnp.stack([jnp.asarray(gate, jnp.float32) if use_gate else z,
                     jnp.asarray(mgate, jnp.float32) if use_mgate else z,
                     jnp.asarray(sw, jnp.float32)])
-    if x_off is not None:
-        x_off = tuple(_pad_to(_pad_to(o, bm, 0), bk, 1) for o in x_off)
     if w_off is not None:
         w_off = tuple(_pad_to(_pad_to(o, bk, 0), bn, 1) for o in w_off)
 
@@ -200,26 +195,24 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
                     gated=use_gate, mapping_gated=use_mgate)
 
     y = rosa_fused_pallas(
-        xp, wp, gains, sx, gg, x_off, w_off, analog=analog,
-        n_planes=n_planes, radix_bits=pam_bits, qmax=qcfg.qmax,
-        realize_x=realize_x, realize_w=realize_w, use_gate=use_gate,
-        use_mgate=use_mgate, k_real=k, p=p, bm=bm, bn=bn, bk=bk,
-        interpret=not on_tpu())
+        xp, wp, gains, s2, gg, w_off, analog=analog, n_planes=n_planes,
+        radix_bits=pam_bits, qmax=qcfg.qmax, realize_w=realize_w,
+        use_gate=use_gate, use_mgate=use_mgate, k_real=k, p=p, bm=bm,
+        bn=bn, bk=bk, interpret=not on_tpu())
     return y[:m, :n]
 
 
 def preflight(m: int, k: int, n: int, *, bm: int = 128, bn: int = 128,
               bk: int = 128, quant_bits: int = 8, pam_bits: int = 1,
-              realize_x: bool = True, realize_w: bool = True) -> dict:
+              realize_w: bool = True) -> dict:
     """Static tileability/VMEM report for a fused (m, k, n) GEMM — no launch.
 
     Mirrors `rosa_fused_matmul`'s layout: pad every dimension to its block
     multiple, run an (m/bm, n/bn) grid with a k-step inner loop, and hold
-    the x/w blocks, the per-row scale and gate operands, three offset
-    streams per realized side, and the f32 accumulator scratch in VMEM
-    (in/out blocks double-buffered by the pipeline).  Defaults price the
-    worst-case launch (both orientations realized — the mapping-gate
-    superposition the analysis sweep must budget for)."""
+    the x/w blocks, the per-row scale and gate operands, the weights'
+    three offset streams when they realize, and the f32 accumulator
+    scratch in VMEM (in/out blocks double-buffered by the pipeline).
+    Defaults price the worst-case launch (realized weights)."""
     n_planes = -(-Q.QuantConfig(bits=quant_bits).n_planes // pam_bits)
     issues: list[str] = []
     if min(m, k, n) <= 0 or min(bm, bn, bk) <= 0:
@@ -238,10 +231,9 @@ def preflight(m: int, k: int, n: int, *, bm: int = 128, bn: int = 128,
     kp = -(-k // bk) * bk
     np_ = -(-n // bn) * bn
     grid = (mp // bm, np_ // bn, kp // bk)
-    x_streams = 1 + 3 * realize_x            # x + its offset operands
     w_streams = 1 + 3 * realize_w            # w + its offset operands
-    vmem = 4 * (2 * (x_streams * bm * bk + w_streams * bk * bn)
-                + 2 * (3 * bm + 3)           # scale + gate operands (dbuf)
+    vmem = 4 * (2 * (bm * bk + w_streams * bk * bn)
+                + 2 * (bm + 3)               # scale + gate operands (dbuf)
                 + 2 * bm * bn                # double-buffered out block
                 + bm * bn                    # accumulator scratch
                 + n_planes)                  # slot gains

@@ -2,8 +2,8 @@
 
 Shapes in model-land are (B, L, H, P) with per-head state (B, H, S, P); this
 wrapper folds (B, H) -> BH, pads L to the chunk multiple with identity steps
-(log a = 0, b = c = 0 contribute nothing and leave the state untouched), and
-falls back to interpret mode off-TPU.
+(log a = 0, b = c = 0 contribute nothing and leave the state untouched).
+Off-TPU the kernel runs under the Pallas interpreter (the CPU test path).
 """
 
 from __future__ import annotations
@@ -42,7 +42,8 @@ def preflight(bsz: int, l: int, h: int, p: int, s_dim: int, *,
     if chunk % 8:
         issues.append(f"chunk={chunk} not a multiple of 8 (sublane tile)")
     lp = -(-l // chunk) * chunk
-    vmem = 4 * (2 * (chunk * p + chunk + 2 * chunk * s_dim)  # in blocks
+    # the (chunk, 1) cumulative-decay block fills 128-lane rows in VMEM
+    vmem = 4 * (2 * (chunk * p + chunk * 128 + 2 * chunk * s_dim)  # in
                 + 2 * chunk * p                              # out block
                 + s_dim * p)                                 # state scratch
     return {"kernel": "ssd_scan", "grid": (bsz * h, lp // chunk),

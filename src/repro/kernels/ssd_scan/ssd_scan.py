@@ -27,10 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
-
-def _kernel(x_ref, loga_ref, b_ref, c_ref, y_ref, sf_ref, state_ref,
+def _kernel(x_ref, lcum_ref, b_ref, c_ref, y_ref, sf_ref, state_ref,
             *, n_chunks: int):
     ci = pl.program_id(1)
 
@@ -39,15 +37,17 @@ def _kernel(x_ref, loga_ref, b_ref, c_ref, y_ref, sf_ref, state_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0]                                  # (Q, P)
-    loga = loga_ref[0]                            # (Q,)
+    lcum = lcum_ref[0]                            # (Q, 1)
     b = b_ref[0]                                  # (Q, S)
     c = c_ref[0]                                  # (Q, S)
     q = x.shape[0]
 
-    lcum = jnp.cumsum(loga)
-    ltot = lcum[-1]
+    # chunk total = last row, picked by a masked reduction (exact: every
+    # other term is +0) because Mosaic cannot broadcast a sliced (1, 1)
+    last = jax.lax.broadcasted_iota(jnp.int32, lcum.shape, 0) == q - 1
+    ltot = jnp.sum(jnp.where(last, lcum, 0.0))
     # intra-chunk: masked decay kernel (rows i, cols j), j <= i
-    dmat = jnp.exp(lcum[:, None] - lcum[None, :])
+    dmat = jnp.exp(lcum - lcum.T)
     row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
     att = jnp.dot(c, b.T, preferred_element_type=jnp.float32)
@@ -55,11 +55,10 @@ def _kernel(x_ref, loga_ref, b_ref, c_ref, y_ref, sf_ref, state_ref,
     y = jnp.dot(att, x, preferred_element_type=jnp.float32)
     # inter-chunk: contribution of the carried state
     s = state_ref[...]                            # (S, P)
-    y = y + jnp.exp(lcum)[:, None] * jnp.dot(c, s,
-                                             preferred_element_type=jnp.float32)
+    y = y + jnp.exp(lcum) * jnp.dot(c, s, preferred_element_type=jnp.float32)
     # carry the state forward
-    w = jnp.exp(ltot - lcum)
-    s_new = jnp.exp(ltot) * s + jnp.dot((b * w[:, None]).T, x,
+    w = jnp.exp(ltot - lcum)                      # (Q, 1)
+    s_new = jnp.exp(ltot) * s + jnp.dot((b * w).T, x,
                                         preferred_element_type=jnp.float32)
     state_ref[...] = s_new
     y_ref[0] = y.astype(y_ref.dtype)
@@ -77,11 +76,17 @@ def ssd_scan_pallas(x: jax.Array, loga: jax.Array, b: jax.Array,
 
     x: (BH, L, P); loga: (BH, L) = log decay; b, c: (BH, L, S).
     Returns (y: (BH, L, P), s_final: (BH, S, P)).  L % chunk == 0.
+
+    The within-chunk cumulative log decay is summed here (Mosaic has no
+    cumsum) and enters the kernel as (BH, L, 1): a trailing unit dim keeps
+    its (1, chunk, 1) block within the TPU's last-two-dims tiling rule.
     """
     bh, l, p = x.shape
     s_dim = b.shape[-1]
     assert l % chunk == 0, (l, chunk)
     n_chunks = l // chunk
+    lcum = jnp.cumsum(loga.reshape(bh, n_chunks, chunk), axis=-1)
+    lcum = lcum.reshape(bh, l, 1)
 
     kernel = functools.partial(_kernel, n_chunks=n_chunks)
     grid = (bh, n_chunks)
@@ -90,7 +95,7 @@ def ssd_scan_pallas(x: jax.Array, loga: jax.Array, b: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
+            pl.BlockSpec((1, chunk, 1), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, s_dim), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, s_dim), lambda i, j: (i, j, 0)),
         ],
@@ -103,8 +108,8 @@ def ssd_scan_pallas(x: jax.Array, loga: jax.Array, b: jax.Array,
             jax.ShapeDtypeStruct((bh, s_dim, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((s_dim, p), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, loga, b, c)
+    )(x, lcum, b, c)
     return y, sf

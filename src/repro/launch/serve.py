@@ -75,6 +75,7 @@ def _run_stream(args) -> None:
     """Continuous-batching / one-shot serving over a synthetic stream."""
     import contextlib
 
+    from repro.launch.mesh import make_mesh
     from repro.serve import (Scheduler, ServeConfig, poisson_requests,
                              report_metrics)
 
@@ -95,7 +96,7 @@ def _run_stream(args) -> None:
                        rosa=args.rosa, variation_seed=args.variation_seed)
     mesh = None
     if args.devices > 1:
-        mesh = jax.make_mesh((args.devices,), ("data",))
+        mesh = make_mesh((args.devices,), ("data",))
     with ctx:
         sched = Scheduler(cfg, scfg, init_seed=args.seed, mesh=mesh)
         print(f"arch={cfg.name} params={sched.bundle.n_params:,} "
@@ -164,6 +165,8 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.policy == "batch":
         _run_batch(args)
     else:

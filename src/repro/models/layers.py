@@ -306,10 +306,9 @@ def flash_decode(q: jax.Array, kc: jax.Array, vc: jax.Array,
     batch_part = spec_kv[0] if len(spec_kv) else None
     q_spec = jax.sharding.PartitionSpec(batch_part)     # match kv's batch
     pos_spec = jax.sharding.PartitionSpec(batch_part)
-    from repro.distributed.sharding import shard_map_compat
-    return shard_map_compat(local, mesh=mesh,
-                            in_specs=(q_spec, spec_kv, spec_kv, pos_spec),
-                            out_specs=q_spec)(q, kc, vc, pos)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(q_spec, spec_kv, spec_kv, pos_spec),
+                         out_specs=q_spec, check_vma=False)(q, kc, vc, pos)
 
 
 def attn_decode(p: dict, cfg: AttnConfig, x: jax.Array, cache: tuple,

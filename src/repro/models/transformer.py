@@ -168,11 +168,10 @@ def _ffn_apply(p: dict, cfg: ModelConfig, x: jax.Array,
             MOE.moe_ep_local, cfg=cfg.moe, model_axis="model",
             fsdp_axes=fsdp, a2a=a2a)
         specs = ep_param_specs(p, fsdp)
-        from repro.distributed.sharding import shard_map_compat
-        return shard_map_compat(
+        return jax.shard_map(
             lambda pl_, xl: fn(pl_, x_local=xl),
             mesh=mesh, in_specs=(specs, x_spec),
-            out_specs=x_spec)(p, x)
+            out_specs=x_spec, check_vma=False)(p, x)
     return MOE.moe_ref(p, cfg.moe, x)
 
 

@@ -9,11 +9,14 @@ noise-placed operands into outputs:
     ref     pure-jnp OSA pipeline (signed-digit planes + slot gains, Eq. 1)
             — the oracle, honours OSAConfig non-idealities.
     pallas  the Pallas TPU kernel in kernels/osa_matmul (bit-plane
-            decomposition + per-plane MXU matmuls), interpret-mode on CPU.
+            decomposition + per-plane MXU matmuls).
+    fused   the Pallas TPU megakernel in kernels/rosa_fused: quantize,
+            MRR realization, shift-and-add and dequantize in one launch.
 
 Backends are registered by name (`register_backend`) and selected by
-`RosaConfig.backend`; the default "auto" resolves per platform (pallas on
-TPU, ref elsewhere).  This replaces the old `use_kernel: bool` toggle.
+`RosaConfig.backend`; the default "auto" resolves per platform (fused on
+TPU, ref elsewhere).  Off-TPU the Pallas kernels run under the
+interpreter, which is the CPU test path only.
 
 Forward semantics (mixed digital-analog mode, Sec. 2-3.1):
 

@@ -31,8 +31,8 @@ docs/adaptive-serving.md).
 from repro.serve.config import ServeConfig, serving_model_config
 from repro.serve.decode import (DecodeState, PrefillTask, init_state,
                                 make_admit, make_admit_step, make_chunk_fn,
-                                make_evict, make_serve_step, null_admit,
-                                sample_token)
+                                make_evict, make_prefill_fn, make_serve_step,
+                                null_admit, sample_token)
 from repro.serve.loadgen import poisson_requests
 from repro.serve.metrics import (build_serving_engine, energy_metrics,
                                  report_metrics, smoke_report)
@@ -44,7 +44,7 @@ __all__ = [
     "Completion", "DecodeState", "EmptyStat", "PrefillTask", "Request",
     "Scheduler", "ServeConfig", "ServeReport", "TickHook",
     "build_serving_engine", "energy_metrics", "init_state", "make_admit",
-    "make_admit_step", "make_chunk_fn", "make_evict", "make_serve_step",
-    "null_admit", "poisson_requests", "report_metrics", "run_sequential",
-    "sample_token", "serving_model_config", "smoke_report",
+    "make_admit_step", "make_chunk_fn", "make_evict", "make_prefill_fn",
+    "make_serve_step", "null_admit", "poisson_requests", "report_metrics",
+    "run_sequential", "sample_token", "serving_model_config", "smoke_report",
 ]

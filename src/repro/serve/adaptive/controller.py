@@ -50,7 +50,7 @@ from repro.rosa.engine import engine_context
 from repro.serve.adaptive.probes import ProbeConfig, ProbeSet
 from repro.serve.adaptive.detector import DetectorConfig, DriftDetector
 from repro.serve.decode import (_step_body, make_admit_step, make_chunk_fn,
-                                make_evict)
+                                make_evict, make_prefill_fn)
 from repro.serve.scheduler import TickHook, _ledger_scope
 
 
@@ -321,7 +321,7 @@ class AdaptiveController(DriftMonitor):
             jax.block_until_ready(warm_out[0].tok)
             new_admit = make_admit_step(bundle, scfg, program=new_prog)
             new_chunk = make_chunk_fn(bundle, program=new_prog)
-            new_whole = new_prog.bind(bundle.prefill)
+            new_whole = make_prefill_fn(bundle, program=new_prog)
             new_evict = make_evict(bundle, scfg, program=new_prog) \
                 if scfg.evict_on_done else None
             # the swap: host-side pointer writes between ticks — in-flight

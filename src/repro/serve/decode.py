@@ -178,6 +178,27 @@ def make_admit_step(bundle: ModelBundle, scfg: ServeConfig, program=None):
     return _jitter(program)(admit, donate_argnums=(0,))
 
 
+def slot_state_specs(bundle: ModelBundle, scfg: ServeConfig,
+                     mesh) -> DecodeState:
+    """PartitionSpecs of a `DecodeState` whose slots are sharded over
+    every axis of `mesh` (the sampling key is replicated)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import slot_dim_specs
+
+    if scfg.n_slots % mesh.size:
+        raise ValueError(f"n_slots={scfg.n_slots} not divisible by "
+                         f"mesh size {mesh.size}")
+    axes = tuple(mesh.shape)
+    cache_specs = slot_dim_specs(
+        cache_axes(bundle.cfg),
+        jax.eval_shape(lambda: T.init_cache(bundle.cfg, scfg.n_slots,
+                                            scfg.max_len)), axes)
+    vec = P(axes if len(axes) > 1 else axes[0])
+    return DecodeState(cache=cache_specs, tok=vec, rid=vec, tidx=vec,
+                       budget=vec, active=vec, key=P())
+
+
 def make_serve_step(bundle: ModelBundle, scfg: ServeConfig, mesh=None,
                     program=None):
     """-> step(params, state, admit, temperature) -> (state, out), jitted
@@ -195,22 +216,12 @@ def make_serve_step(bundle: ModelBundle, scfg: ServeConfig, mesh=None,
 
         return _jitter(program)(step, donate_argnums=(1,))
 
-    from repro.distributed.sharding import shard_map_compat, slot_dim_specs
     from jax.sharding import PartitionSpec as P
 
-    d = int(np.prod(list(mesh.shape.values())))
-    if scfg.n_slots % d:
-        raise ValueError(f"n_slots={scfg.n_slots} not divisible by "
-                         f"mesh size {d}")
     axes = tuple(mesh.shape)             # shard slots over ALL mesh axes
-    n_local = scfg.n_slots // d
-
-    cache_specs = slot_dim_specs(cache_axes(bundle.cfg),
-                                 T.init_cache(bundle.cfg, scfg.n_slots,
-                                              scfg.max_len), axes)
-    vec = P(axes if len(axes) > 1 else axes[0])
-    state_specs = DecodeState(cache=cache_specs, tok=vec, rid=vec,
-                              tidx=vec, budget=vec, active=vec, key=P())
+    n_local = scfg.n_slots // mesh.size
+    state_specs = slot_state_specs(bundle, scfg, mesh)
+    vec = state_specs.tok
     admit_specs = jax.tree.map(lambda _: P(),
                                null_admit(bundle.cfg, scfg))
     out_specs = {"token": vec, "emitted": vec, "done": vec, "pos": vec}
@@ -224,10 +235,10 @@ def make_serve_step(bundle: ModelBundle, scfg: ServeConfig, mesh=None,
         return _step_body(bundle, scfg, params, state, admit, temperature,
                           idx * n_local)
 
-    sharded = shard_map_compat(
+    sharded = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), state_specs, admit_specs, P()),
-        out_specs=(state_specs, out_specs))
+        out_specs=(state_specs, out_specs), check_vma=False)
     return _jitter(program)(sharded, donate_argnums=(1,))
 
 
@@ -273,7 +284,7 @@ class PrefillTask:
         self._chunk_fn = chunk_fn if chunk_fn is not None \
             else make_chunk_fn(bundle)
         self._whole_fn = whole_fn if whole_fn is not None \
-            else jax.jit(bundle.prefill)
+            else make_prefill_fn(bundle)
         self._off = 0
         self.cache = (T.init_cache(bundle.cfg, 1, scfg.max_len)
                       if self.chunked else None)
@@ -316,10 +327,27 @@ class PrefillTask:
         return self.done
 
 
-def make_chunk_fn(bundle: ModelBundle, program=None):
+def _replicated(fn, mesh):
+    """With a slot `mesh`, run `fn` whole on every device's replica of the
+    weights: a request's prefill is batch 1 and cannot be split over
+    slots, and a Pallas TPU kernel is never partitioned automatically."""
+    if mesh is None:
+        return fn
+    from jax.sharding import PartitionSpec as P
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+def make_chunk_fn(bundle: ModelBundle, program=None, mesh=None):
     """The shared jitted chunk step; ONLY the request cache is donated
     (tokens/n_valid are rebuilt per chunk and too small to matter)."""
-    return _jitter(program)(
-        lambda params, tokens, n_valid, cache: bundle.chunk_step(
-            params, {"tokens": tokens, "n_valid": n_valid, "cache": cache}),
-        donate_argnums=(3,))
+    def chunk(params, tokens, n_valid, cache):
+        return bundle.chunk_step(
+            params, {"tokens": tokens, "n_valid": n_valid, "cache": cache})
+
+    return _jitter(program)(_replicated(chunk, mesh), donate_argnums=(3,))
+
+
+def make_prefill_fn(bundle: ModelBundle, program=None, mesh=None):
+    """The jitted whole-prompt prefill (ssm and hybrid families)."""
+    return _jitter(program)(_replicated(bundle.prefill, mesh))

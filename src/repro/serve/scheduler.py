@@ -40,7 +40,8 @@ from repro.obs import trace as obs
 from repro.serve.config import ServeConfig, serving_model_config
 from repro.serve.decode import (PrefillTask, init_state, make_admit,
                                 make_admit_step, make_chunk_fn, make_evict,
-                                make_serve_step, null_admit, sample_token)
+                                make_prefill_fn, make_serve_step, null_admit,
+                                sample_token, slot_state_specs)
 
 
 @dataclasses.dataclass
@@ -228,17 +229,32 @@ class Scheduler:
             self.engine = self.program.engine
         elif engine is not None:
             self.program = serving_program(self.bundle, scfg, engine)
+        # with a mesh, params are replicated on every device and the slot
+        # state is sharded over it up front, so no step reshards them
+        self.state_sharding = None
         with self._engine_ctx():
-            self.params = (params if params is not None
-                           else self.bundle.init(jax.random.PRNGKey(init_seed)))
+            if mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+                everywhere = NamedSharding(mesh, P())
+                self.params = (
+                    jax.device_put(params, everywhere) if params is not None
+                    else jax.jit(self.bundle.init, out_shardings=everywhere)(
+                        jax.random.PRNGKey(init_seed)))
+                self.state_sharding = jax.tree.map(
+                    lambda s: NamedSharding(mesh, s),
+                    slot_state_specs(self.bundle, scfg, mesh))
+            else:
+                self.params = (params if params is not None
+                               else self.bundle.init(
+                                   jax.random.PRNGKey(init_seed)))
         self.step = make_serve_step(self.bundle, scfg, mesh=mesh,
                                     program=self.program)
         self.admit_step = make_admit_step(self.bundle, scfg,
                                           program=self.program)
-        self.chunk_fn = make_chunk_fn(self.bundle, program=self.program)
-        self.whole_fn = (self.program.bind(self.bundle.prefill)
-                         if self.program is not None
-                         else jax.jit(self.bundle.prefill))
+        self.chunk_fn = make_chunk_fn(self.bundle, program=self.program,
+                                      mesh=mesh)
+        self.whole_fn = make_prefill_fn(self.bundle, program=self.program,
+                                        mesh=mesh)
         self.evict = make_evict(self.bundle, scfg, program=self.program) \
             if scfg.evict_on_done else None
         self.null = null_admit(self.cfg, scfg)
@@ -301,6 +317,8 @@ class Scheduler:
         slot_rid: list[int | None] = [None] * n_slots
         n_done = 0
         state = init_state(self.cfg, scfg)
+        if self.state_sharding is not None:
+            state = jax.device_put(state, self.state_sharding)
         rep = ServeReport(policy=policy, completions=completions,
                           n_slots=n_slots)
         tick = 0
@@ -547,8 +565,7 @@ def run_sequential(model_cfg, scfg: ServeConfig, params,
         from repro import rosa
         ctx = rosa.engine_context(engine)
     chunk_fn = make_chunk_fn(bundle, program=program)
-    whole_fn = (program.bind(bundle.prefill) if program is not None
-                else jax.jit(bundle.prefill))
+    whole_fn = make_prefill_fn(bundle, program=program)
     decode1_fn = lambda p, t, c: bundle.decode_step(
         p, {"token": t, "pos": c["pos"], "cache": c})
     decode1 = (program.bind(decode1_fn) if program is not None

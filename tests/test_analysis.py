@@ -172,7 +172,7 @@ class TestRecompile:
         # without x64 enabled nothing promotes; build the hazard directly
         def g(x):
             return jax.lax.convert_element_type(x, jnp.float64)
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             fs = run_checks([AnalysisTarget("t", g, (_sds(4),))],
                             checks=["recompile"])
         assert "REC002" in codes(fs)

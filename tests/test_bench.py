@@ -165,6 +165,8 @@ def test_runner_records_failures_and_continues(monkeypatch, capsys):
 
     monkeypatch.setattr(R, "BENCHES", {"bad": bad_bench, "ok": ok_bench,
                                        "skip": skip_bench})
+    # main() would point this worker's compilation cache at the checkout
+    monkeypatch.setattr(R, "enable_compile_cache", lambda: None)
     results = R.run_benches(["bad", "ok", "skip"], quick=True)
     assert calls == ["bad", "ok"]          # ok still ran after the failure
     by = {r.name: r for r in results}

@@ -54,7 +54,8 @@ def test_elastic_restore_recreates_sharding(tmp_path, key):
     """Arrays are stored topology-free; restore re-places per sharding."""
     t = _tree(key)
     ckpt.save(str(tmp_path), 3, t)
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), t)
     out = ckpt.restore(str(tmp_path), 3, jax.tree.map(jnp.zeros_like, t),

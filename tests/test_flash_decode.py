@@ -17,9 +17,10 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.sharding import SERVE_RULES, use_sharding, resolve_spec
+from repro.launch.mesh import make_mesh
 from repro.models import layers as L
 
-mesh = jax.make_mesh((1, 4), ("data", "model"))
+mesh = make_mesh((1, 4), ("data", "model"))
 cfg = L.AttnConfig(d_model=32, n_heads=4, n_kv_heads=2, head_dim=8)
 key = jax.random.PRNGKey(0)
 B, S = 2, 64

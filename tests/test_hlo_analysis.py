@@ -43,15 +43,15 @@ def test_scan_trip_count_multiplies():
 
 
 def test_collectives_detected_in_psum():
-    mesh = jax.make_mesh((1,), ("d",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("d",))
     from jax.sharding import PartitionSpec as P
 
     def f(x):
         return jax.lax.psum(x * 2.0, "d")
 
-    from repro.distributed.sharding import shard_map_compat
-    g = jax.jit(shard_map_compat(f, mesh=mesh, in_specs=P("d"),
-                                 out_specs=P()))
+    g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("d"),
+                              out_specs=P(), check_vma=False))
     comp = g.lower(jax.ShapeDtypeStruct((16,), jnp.float32)).compile()
     rep = analyze(comp.as_text())
     # single-device psum may be optimised away; just assert no crash and

@@ -212,14 +212,15 @@ def assert_quantized_parity(y, y_ref, *, qmax: int = 127,
     """Parity assertion for two implementations of the same quantized
     pipeline computed in different float op orders.
 
-    The fused kernel re-derives the realization chain with noise/variation
-    folded into additive offsets, so a conditioned activation can differ
-    from the composed chain's by ~1 ulp; when such a value lands within
-    float noise of a requantization rounding boundary its 8-bit code flips
-    by ONE.  A flip moves every output of that activation row by at most
-    one requant LSB (~1/qmax of the output's full scale).  So: the bulk
-    must match at float-accumulation tightness, deviations may never
-    exceed the one-LSB bound, and flipped rows must stay rare."""
+    When a conditioned activation that feeds a requantization differs
+    between the two by ~1 ulp and lands within float noise of a rounding
+    boundary, its 8-bit code flips by ONE.  A flip moves every output of
+    that activation row by at most one requant LSB (~1/qmax of the
+    output's full scale).  So: the bulk must match at float-accumulation
+    tightness, deviations may never exceed the one-LSB bound, and flipped
+    rows must stay rare.  (The fused kernel contracts the codes the
+    composed chain's own functions compute, so it leaves only the weight
+    realization's float order and the accumulation order.)"""
     y = np.asarray(y, np.float64).reshape(-1, y.shape[-1])
     r = np.asarray(y_ref, np.float64).reshape(y.shape)
     scale = max(float(np.max(np.abs(r))), 1.0)

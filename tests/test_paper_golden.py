@@ -19,6 +19,7 @@ profile_layers_fast) are exercised, plus an element-level parity check
 between the two, so neither implementation can drift from the other.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -127,7 +128,6 @@ def test_energy_vec_matches_scalar_on_paper_layers():
     """core.energy_vec and core.energy agree per layer to 1e-9 relative on
     every paper workload row, both mappings — the golden pins above hold
     through EITHER implementation."""
-    from jax.experimental import enable_x64
 
     from repro.core import energy_vec as EV
 
@@ -135,7 +135,7 @@ def test_energy_vec_matches_scalar_on_paper_layers():
         cand = EV.stack_candidates([ROSA_OPTIMAL])
         stacked = EV.stack_layers(layers)
         for mp in (Mapping.IS, Mapping.WS):
-            with enable_x64():
+            with jax.enable_x64():
                 spec = EV.EnergySpec.make(mapping=mp,
                                           mode=ComputeMode.MIXED,
                                           osa=E.OSA_OPTIMAL, batch=128)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_cross_program_close
 
 from repro import rosa
 from repro.core import mapping as M
@@ -165,12 +166,11 @@ def test_program_matches_eager_toy(key):
     args = _args(key)
     prog = rosa.compile(_net, eng, args, autotune=TUNE, cache=False)
     eager = _net(eng.with_plan(prog.plan), *args)
-    np.testing.assert_array_equal(np.asarray(prog(*args)),
-                                  np.asarray(eager))
+    assert_cross_program_close(prog(*args), eager)
     # explicit key threading == eager engine with that base key
     k2 = jax.random.PRNGKey(9)
-    np.testing.assert_array_equal(
-        np.asarray(prog(*args, key=k2)),
+    assert_cross_program_close(
+        prog(*args, key=k2),
         np.asarray(_net(eng.with_plan(prog.plan).with_key(k2), *args)))
     assert float(jnp.max(jnp.abs(prog(*args, key=k2) - prog(*args)))) > 0
 
@@ -183,7 +183,7 @@ def test_program_variation_threading(key):
                                     jnp.asarray(0.0))}
     y = prog(*args, variation=var)
     eager = _net(eng.with_variation(var), *args)
-    np.testing.assert_array_equal(np.asarray(y), np.asarray(eager))
+    assert_cross_program_close(y, eager)
     assert float(jnp.max(jnp.abs(y - prog(*args)))) > 0
 
 
@@ -203,8 +203,7 @@ def test_program_matches_eager_cnn(key):
     x = jax.random.normal(key, (4, 32, 32, 3))
     eager = cnn_apply(params, specs, x, eng,
                       residual_from=LITE_SKIPS.get(model))
-    np.testing.assert_array_equal(np.asarray(prog(params, x)),
-                                  np.asarray(eager))
+    assert_cross_program_close(prog(params, x), eager)
 
 
 def test_program_matches_eager_transformer(key):
@@ -266,9 +265,8 @@ def test_program_bind_installs_engine(key):
         return rosa.ambient_engine().matmul(x, w, name="a")
 
     bound = prog.bind(ambient_fn)
-    np.testing.assert_array_equal(
-        np.asarray(bound(args[0], args[1])),
-        np.asarray(eng.matmul(args[0], args[1], name="a")))
+    assert_cross_program_close(bound(args[0], args[1]),
+                               eng.matmul(args[0], args[1], name="a"))
 
 
 def test_dense_program_is_plain_matmul(key):
