@@ -3,8 +3,9 @@
 The observability layer the serving/robustness roadmap items build on:
 
 * `trace` — hierarchical span tracer with Chrome-trace JSON export
-  (Perfetto-loadable); ambient installation via `tracing`, zero-cost
-  module-level helpers (`span`, `instant`, `counter`, async events);
+  (Perfetto-loadable); ambient installation via `tracing`, module-level
+  helpers (`span`, `instant`, `counter`, async events) whose spans also
+  reach the JAX profiler, as one TraceMe when no tracer is installed;
 * `metrics` — thread-safe registry of counters/gauges/bounded histograms
   with bench-schema and Prometheus exports, plus `jax.monitoring` hooks
   for XLA retrace / compile-cache counters;
@@ -33,6 +34,7 @@ from repro.obs.trace import (
     enabled,
     instant,
     span,
+    span_on,
     traced,
     tracing,
 )
@@ -54,6 +56,7 @@ __all__ = [
     "instant",
     "registry",
     "span",
+    "span_on",
     "swap_registry",
     "traced",
     "tracing",

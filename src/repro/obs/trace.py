@@ -7,11 +7,14 @@ as a Chrome trace (the ``traceEvents`` JSON format) loadable in Perfetto
 
 Design constraints, in order:
 
-1. **Zero-cost when disabled.**  Instrumented code calls the module-level
-   helpers (`span`, `instant`, `counter`, ...), which consult a
-   `contextvars.ContextVar` — exactly the ambient-engine pattern of
-   `rosa.engine_context` — and collapse to a shared no-op when no tracer
-   is installed.  The `obs_overhead` bench gates the residual overhead.
+1. **One TraceMe when disabled.**  Instrumented code calls the
+   module-level helpers (`span`, `instant`, `counter`, ...), which consult
+   a `contextvars.ContextVar` — exactly the ambient-engine pattern of
+   `rosa.engine_context`.  With no tracer installed, `instant`, `counter`
+   and the async helpers do nothing, and a span is one
+   `jax.profiler.TraceAnnotation` (a TraceMe: about a microsecond with no
+   profiler session, a host event on the profiler's clock inside one).
+   The `obs_overhead` bench gates the residual overhead.
 2. **Thread/task safety.**  Installation is context-local (`tracing`),
    event emission is lock-guarded, and span nesting needs no explicit
    stack: complete ("X") events nest by time containment per (pid, tid),
@@ -20,6 +23,10 @@ Design constraints, in order:
    its real duration even when the body raises; the raising span is
    annotated with the exception type so failed stages are visible on the
    timeline.
+
+Two sinks see every span under the same name: this module's Chrome-JSON
+`Tracer`, on `perf_counter` (it has no device events to line up with), and
+the JAX profiler, whose host line puts the span beside the device's ops.
 
 Usage::
 
@@ -40,6 +47,8 @@ import os
 import threading
 import time
 from typing import Any, Callable
+
+from jax.profiler import TraceAnnotation
 
 _TRACER_VAR: contextvars.ContextVar["Tracer | None"] = \
     contextvars.ContextVar("repro_obs_tracer", default=None)
@@ -226,43 +235,61 @@ class Tracer:
 
 
 class _SpanCtx:
-    """A hand-rolled span context manager.
+    """A hand-rolled, re-enterable span context manager.
 
     This is the hot path of the tracer (one instance per span, several per
     scheduler tick), so it avoids ``contextlib.contextmanager``'s generator
     machinery — that alone is ~3x the cost of the whole emission.
+
+    Every use also enters one profiler annotation under the same name,
+    built afresh on each ``__enter__`` (a TraceMe is never reused).  With
+    ``tr`` None the span goes to the profiler only.
     """
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_ann")
 
-    def __init__(self, tr: Tracer, name: str, cat: str, args: dict):
+    def __init__(self, tr: "Tracer | None", name: str, cat: str,
+                 args: dict):
         self._tr = tr
         self._name = name
         self._cat = cat
         self._args = args
 
-    def __enter__(self) -> Tracer:
-        self._t0 = self._tr._clock()        # raw clock; converted at export
+    def __enter__(self) -> "Tracer | None":
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
+        if self._tr is not None:
+            self._t0 = self._tr._clock()    # raw clock; converted at export
         return self._tr
 
     def __exit__(self, etype, exc, tb) -> bool:
         tr = self._tr
-        tr._append(("X", self._name, self._cat, self._t0, tr._clock(),
-                    self._args, None if etype is None else etype.__name__,
-                    threading.get_ident()))
+        if tr is not None:
+            tr._append(("X", self._name, self._cat, self._t0, tr._clock(),
+                        self._args,
+                        None if etype is None else etype.__name__,
+                        threading.get_ident()))
+        self._ann.__exit__(etype, exc, tb)
         return False
 
 
 # ---------------------------------------------------------------------------
-# Module-level helpers — the zero-cost-when-disabled instrumentation API
+# Module-level helpers — the instrumentation API (one TraceMe when disabled)
 # ---------------------------------------------------------------------------
-_NULL_SPAN = contextlib.nullcontext()
-
-
 def span(name: str, cat: str = "", **args: Any):
-    """`Tracer.span` on the ambient tracer, or a shared no-op context."""
+    """`Tracer.span` on the ambient tracer, or, with none installed, one
+    `jax.profiler.TraceAnnotation` for a single use."""
     tr = _TRACER_VAR.get()
-    return _NULL_SPAN if tr is None else _SpanCtx(tr, name, cat or "span", args)
+    if tr is None:
+        return TraceAnnotation(name, **args)
+    return _SpanCtx(tr, name, cat or "span", args)
+
+
+def span_on(tr: "Tracer | None", name: str, cat: str = "",
+            **args: Any) -> _SpanCtx:
+    """A re-enterable span recorded on `tr` (None: the profiler only), for
+    loops that resolve the tracer once and enter the same span per step."""
+    return _SpanCtx(tr, name, cat or "span", args)
 
 
 def instant(name: str, cat: str = "", **args: Any) -> None:

@@ -565,10 +565,12 @@ class Program:
         so model code that resolves `rosa.ambient_engine()` sees the
         program's frozen (plan, chip, ledger) — this is how the serving
         scheduler builds its decode/prefill/admit steps from one Program
-        without any global engine stack.
+        without any global engine stack.  The jitted function keeps
+        `fn`'s name, so the profiler shows its program as ``jit_<name>``.
         """
         engine = self.engine
 
+        @functools.wraps(fn)
         def wrapped(*args, **kwargs):
             """Run `fn` with this program's engine ambient."""
             with engine_context(engine):

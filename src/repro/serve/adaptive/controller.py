@@ -65,7 +65,7 @@ def make_drift_step(bundle, scfg, program):
     engine = program.engine
     chip = dict(engine.variation or {})
 
-    def step(params, state, admit, temperature, resid_k):
+    def serve_decode_step(params, state, admit, temperature, resid_k):
         eng = engine
         if chip:
             eng = engine.with_variation(V.shift_thermal(chip, resid_k))
@@ -73,7 +73,7 @@ def make_drift_step(bundle, scfg, program):
             return _step_body(bundle, scfg, params, state, admit,
                               temperature, jnp.zeros((), jnp.int32))
 
-    return jax.jit(step, donate_argnums=(1,))
+    return jax.jit(serve_decode_step, donate_argnums=(1,))
 
 
 class ControllerState(enum.IntEnum):

@@ -171,11 +171,11 @@ def make_admit_step(bundle: ModelBundle, scfg: ServeConfig, program=None):
     its batch with this, then decodes; the continuous policy never needs
     it (its admissions ride inside `make_serve_step`)."""
 
-    def admit(state: DecodeState, payload: dict) -> DecodeState:
+    def serve_admit(state: DecodeState, payload: dict) -> DecodeState:
         return _apply_admission(bundle.cfg, state, payload,
                                 jnp.zeros((), jnp.int32))
 
-    return _jitter(program)(admit, donate_argnums=(0,))
+    return _jitter(program)(serve_admit, donate_argnums=(0,))
 
 
 def slot_state_specs(bundle: ModelBundle, scfg: ServeConfig,
@@ -210,11 +210,11 @@ def make_serve_step(bundle: ModelBundle, scfg: ServeConfig, mesh=None,
     if mesh is None:
         body = functools.partial(_step_body, bundle, scfg)
 
-        def step(params, state, admit, temperature):
+        def serve_decode_step(params, state, admit, temperature):
             return body(params, state, admit, temperature,
                         jnp.zeros((), jnp.int32))
 
-        return _jitter(program)(step, donate_argnums=(1,))
+        return _jitter(program)(serve_decode_step, donate_argnums=(1,))
 
     from jax.sharding import PartitionSpec as P
 
@@ -228,7 +228,7 @@ def make_serve_step(bundle: ModelBundle, scfg: ServeConfig, mesh=None,
     if scfg.collect_logits:
         out_specs["logits"] = vec
 
-    def local(params, state, admit, temperature):
+    def serve_decode_step(params, state, admit, temperature):
         idx = jnp.zeros((), jnp.int32)
         for a in axes:
             idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
@@ -236,7 +236,7 @@ def make_serve_step(bundle: ModelBundle, scfg: ServeConfig, mesh=None,
                           idx * n_local)
 
     sharded = jax.shard_map(
-        local, mesh=mesh,
+        serve_decode_step, mesh=mesh,
         in_specs=(P(), state_specs, admit_specs, P()),
         out_specs=(state_specs, out_specs), check_vma=False)
     return _jitter(program)(sharded, donate_argnums=(1,))
@@ -247,13 +247,13 @@ def make_evict(bundle: ModelBundle, scfg: ServeConfig, program=None):
     donated).  Admission overwrites slots anyway; eviction guarantees a
     completed request's KV rows don't outlive it (scfg.evict_on_done)."""
 
-    def evict(state: DecodeState, slot):
+    def serve_evict(state: DecodeState, slot):
         return state._replace(
             cache=evict_slot(bundle.cfg, state.cache, slot),
             active=_row_write(state.active, jnp.zeros((1,), bool), slot,
                               True))
 
-    return _jitter(program)(evict, donate_argnums=(0,))
+    return _jitter(program)(serve_evict, donate_argnums=(0,))
 
 
 # ---------------------------------------------------------------------------
@@ -341,13 +341,17 @@ def _replicated(fn, mesh):
 def make_chunk_fn(bundle: ModelBundle, program=None, mesh=None):
     """The shared jitted chunk step; ONLY the request cache is donated
     (tokens/n_valid are rebuilt per chunk and too small to matter)."""
-    def chunk(params, tokens, n_valid, cache):
+    def serve_prefill_chunk(params, tokens, n_valid, cache):
         return bundle.chunk_step(
             params, {"tokens": tokens, "n_valid": n_valid, "cache": cache})
 
-    return _jitter(program)(_replicated(chunk, mesh), donate_argnums=(3,))
+    return _jitter(program)(_replicated(serve_prefill_chunk, mesh),
+                            donate_argnums=(3,))
 
 
 def make_prefill_fn(bundle: ModelBundle, program=None, mesh=None):
     """The jitted whole-prompt prefill (ssm and hybrid families)."""
-    return _jitter(program)(_replicated(bundle.prefill, mesh))
+    def serve_prefill_whole(params, batch):
+        return bundle.prefill(params, batch)
+
+    return _jitter(program)(_replicated(serve_prefill_whole, mesh))

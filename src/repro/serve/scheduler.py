@@ -33,6 +33,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.model import build_model
 from repro.obs import metrics as obs_metrics
@@ -332,15 +333,15 @@ class Scheduler:
         g_depth = reg.gauge("serve.queue_depth")
         g_active = reg.gauge("serve.slots_active")
         last_depth = last_active = -1
-        null_span = contextlib.nullcontext()
-        # span contexts are stateless between uses — build the per-tick ones
-        # once and re-enter them, keeping the hot loop allocation-free
-        if tr is not None:
-            tick_ctx = tr.span("serve.tick", "serve")
-            prefill_ctx = tr.span("serve.prefill_chunk", "serve")
-            decode_ctx = tr.span("serve.decode_step", "serve")
-        else:
-            tick_ctx = prefill_ctx = decode_ctx = null_span
+        # spans reach the profiler's host line whether or not `tr` is set
+        # (then the Chrome sink too); the argument-free ones are built once
+        # and re-entered, each use making one fresh TraceMe
+        prefill_ctx = obs.span_on(tr, "serve.prefill.dispatch", "serve")
+        decode_ctx = obs.span_on(tr, "serve.decode.dispatch", "serve")
+        pull_ctx = obs.span_on(tr, "serve.decode.pull", "serve")
+        # rid -> open `serve.request.queued` annotation (profiler only):
+        # enqueue until the request leaves `prefill_q`, across ticks
+        queued: dict[int, TraceAnnotation] = {}
         etrack = None
         if tr is not None and self.engine is not None \
                 and self.engine.ledger is not None:
@@ -356,14 +357,17 @@ class Scheduler:
                 tr.async_end("request", comp.rid, cat="request",
                              tokens=len(comp.tokens))
 
-        with self._engine_ctx():
+        with self._engine_ctx(), _closing(queued):
             while n_done < len(requests):
-                with tick_ctx:
+                with obs.span_on(tr, "serve.tick", "serve", tick=tick):
                     progressed = False
                     while pending and pending[0].arrival <= tick:
                         r = pending.popleft()
                         completions[r.rid].enqueue_wall = \
                             time.perf_counter() - t0
+                        q = queued[r.rid] = TraceAnnotation(
+                            "serve.request.queued", rid=r.rid)
+                        q.__enter__()
                         if tr is not None:
                             tr.async_begin("request", r.rid, cat="request",
                                            prompt_len=len(r.prompt))
@@ -376,6 +380,7 @@ class Scheduler:
                                                      req.prompt,
                                                      self.chunk_fn,
                                                      self.whole_fn))
+                        queued.pop(req.rid).__exit__(None, None, None)
                     if inflight is not None:
                         req, task = inflight
                         with prefill_ctx, self._scope("prefill"):
@@ -386,9 +391,12 @@ class Scheduler:
                         progressed = True
                         if task.done:
                             comp = completions[req.rid]
-                            tok0 = self.sample1(self.base_key, req.rid, 0,
-                                                task.logits, temp)
-                            comp.tokens.append(int(tok0))
+                            # the host waits here for the prompt's last chunk
+                            with obs.span_on(tr, "serve.prefill.first_token",
+                                             "serve", rid=req.rid):
+                                tok0 = self.sample1(self.base_key, req.rid,
+                                                    0, task.logits, temp)
+                                comp.tokens.append(int(tok0))
                             comp.first_token_tick = tick
                             comp.first_token_wall = \
                                 time.perf_counter() - t0
@@ -450,11 +458,12 @@ class Scheduler:
                             etrack.tick("decode")
                         rep.decode_steps += 1
                         progressed = True
-                        tok = np.asarray(out["token"])
-                        emitted = np.asarray(out["emitted"])
-                        done = np.asarray(out["done"])
-                        logits = (np.asarray(out["logits"])
-                                  if scfg.collect_logits else None)
+                        with pull_ctx:      # the host waits for the step
+                            tok = np.asarray(out["token"])
+                            emitted = np.asarray(out["emitted"])
+                            done = np.asarray(out["done"])
+                            logits = (np.asarray(out["logits"])
+                                      if scfg.collect_logits else None)
                         for s in range(n_slots):
                             if not emitted[s]:
                                 continue
@@ -509,6 +518,18 @@ class Scheduler:
         comp.admit_wall = time.perf_counter() - t0
         if tr is not None:
             tr.async_instant("admit", comp.rid, cat="request", slot=slot)
+
+
+@contextlib.contextmanager
+def _closing(annotations: dict):
+    """Close the annotations still open in `annotations` when the block
+    exits, also by an exception."""
+    try:
+        yield
+    finally:
+        for ann in annotations.values():
+            ann.__exit__(None, None, None)
+        annotations.clear()
 
 
 def serving_program(bundle, scfg: ServeConfig, engine):

@@ -57,8 +57,12 @@ def test_disabled_path_adds_zero_events():
         obs.async_begin("r", 1)
         obs.async_end("r", 1)
     assert len(tr) == n0 == 0
-    # the shared null span is reused, not rebuilt per call
-    assert obs.span("a") is obs.span("b")
+    # disabled, a span is one profiler annotation, fresh per call (a
+    # TraceMe is never reused)
+    from jax.profiler import TraceAnnotation
+    a, b = obs.span("a"), obs.span("b")
+    assert isinstance(a, TraceAnnotation) and isinstance(b, TraceAnnotation)
+    assert a is not b
 
 
 def test_tracing_none_disables_under_outer_tracer():
@@ -345,10 +349,20 @@ def test_scheduler_trace_and_wall_metrics():
     with obs.swap_registry(reg), obs.tracing(tr):
         rep = sched.run(reqs)
 
-    # spans from the tick loop
-    span_names = {e["name"] for e in tr.events if e.get("ph") == "X"}
-    assert {"serve.tick", "serve.prefill_chunk",
-            "serve.decode_step"} <= span_names
+    # spans from the tick loop: one dispatch per chunk and per decode step,
+    # one pull per decode step, one first-token wait per request
+    spans = [e for e in tr.events if e.get("ph") == "X"]
+    count = {n: sum(1 for e in spans if e["name"] == n)
+             for n in ("serve.tick", "serve.prefill.dispatch",
+                       "serve.prefill.first_token", "serve.decode.dispatch",
+                       "serve.decode.pull")}
+    # a loop pass runs at most one chunk and one decode step
+    assert count["serve.tick"] >= max(rep.prefill_chunks,
+                                      rep.decode_steps) > 0
+    assert count["serve.prefill.dispatch"] == rep.prefill_chunks > 0
+    assert count["serve.decode.dispatch"] == rep.decode_steps > 0
+    assert count["serve.decode.pull"] == rep.decode_steps
+    assert count["serve.prefill.first_token"] == len(reqs)
     # request lifecycle: one b/e pair per request + instants
     begins = [e for e in tr.events if e.get("ph") == "b"]
     ends = [e for e in tr.events if e.get("ph") == "e"]
@@ -395,6 +409,154 @@ def test_scheduler_untraced_report_identical():
     gated_off = {m.name: m.value for m in report_metrics(rep_off) if m.gate}
     gated_on = {m.name: m.value for m in report_metrics(rep_on) if m.gate}
     assert gated_off == gated_on
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ---------------------------------------------------------------------------
+def _profiled(fn, logdir):
+    """`fn()` under the JAX profiler inside the benchmark's window
+    annotation -> (its result, [(name, start_ns, dur_ns, stats)] of the
+    host events)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("chipbench.window"):
+            out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True))[-1]
+    events = [(e.name, e.start_ns, e.duration_ns, dict(e.stats))
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    return out, events
+
+
+def _smoke_scheduler():
+    from repro.configs import get_smoke
+    from repro.serve import Scheduler, ServeConfig
+
+    cfg = get_smoke("qwen3-32b")
+    scfg = ServeConfig(n_slots=2, max_len=32, prefill_chunk=8, seed=0)
+    return cfg, Scheduler(cfg, scfg, init_seed=0)
+
+
+@pytest.fixture(scope="module")
+def profiled_run(tmp_path_factory):
+    """The smoke scheduler once with no profiler and once under it, with
+    no obs tracer installed (the benchmark's set-up)."""
+    from repro.serve import poisson_requests
+
+    cfg, sched = _smoke_scheduler()
+    reqs = poisson_requests(6, 1.0, vocab=cfg.vocab, prompt_len=(4, 20),
+                            gen_len=(2, 6), seed=0)
+    assert not obs.enabled()
+    rep_plain = sched.run(reqs)
+    rep, events = _profiled(lambda: sched.run(reqs),
+                            tmp_path_factory.mktemp("xplane"))
+    return reqs, rep, rep_plain, events
+
+
+def _named(events, name):
+    return [e for e in events if e[0] == name]
+
+
+@pytest.mark.parametrize("span,count", [
+    ("serve.decode.pull", "decode_steps"),
+    ("serve.decode.dispatch", "decode_steps"),
+    ("serve.prefill.dispatch", "prefill_chunks"),
+])
+def test_profiler_step_spans_one_per_step(profiled_run, span, count):
+    _, rep, _, events = profiled_run
+    assert len(_named(events, span)) == getattr(rep, count) > 0
+
+
+def test_profiler_request_queued_one_per_request(profiled_run):
+    reqs, _, _, events = profiled_run
+    queued = _named(events, "serve.request.queued")
+    assert sorted(e[3]["rid"] for e in queued) == sorted(r.rid for r in reqs)
+    assert all(e[2] > 0 for e in queued)        # none of zero length
+
+
+def test_profiler_first_token_one_per_request(profiled_run):
+    reqs, _, _, events = profiled_run
+    firsts = _named(events, "serve.prefill.first_token")
+    assert sorted(e[3]["rid"] for e in firsts) == sorted(r.rid for r in reqs)
+
+
+def test_profiler_tick_spans_hold_the_steps(profiled_run):
+    _, rep, _, events = profiled_run
+    ticks = sorted(_named(events, "serve.tick"), key=lambda e: e[1])
+    assert len(ticks) >= max(rep.prefill_chunks, rep.decode_steps) > 0
+    nums = [e[3]["tick"] for e in ticks]
+    assert nums == sorted(set(nums))            # one span per loop pass
+    window = _named(events, "chipbench.window")[0]
+    for name in ("serve.decode.pull", "serve.decode.dispatch",
+                 "serve.prefill.dispatch", "serve.prefill.first_token"):
+        for _, s, d, _ in _named(events, name):
+            assert any(ts <= s and s + d <= ts + td
+                       for _, ts, td, _ in ticks), name
+    assert all(window[1] <= s and s + d <= window[1] + window[2]
+               for _, s, d, _ in ticks)
+
+
+def test_profiler_tokens_identical(profiled_run):
+    _, rep, rep_plain, _ = profiled_run
+    assert rep.decode_steps == rep_plain.decode_steps
+    assert rep.prefill_chunks == rep_plain.prefill_chunks
+    for rid, c in rep_plain.completions.items():
+        assert rep.completions[rid].tokens == c.tokens
+
+
+def test_profiler_queued_spans_closed_when_run_raises(tmp_path):
+    """A request still queued when `run` raises has its span closed."""
+    from repro.serve import Request, TickHook
+
+    cfg, sched = _smoke_scheduler()
+    reqs = [Request(rid=i, prompt=np.arange(5 + i) % cfg.vocab,
+                    max_new_tokens=3, arrival=0) for i in range(3)]
+
+    class Boom(TickHook):
+        def on_tick_end(self, sched, tick, state, idle_slots):
+            raise RuntimeError("boom")
+
+    def run():
+        with pytest.raises(RuntimeError, match="boom"):
+            sched.run(reqs, hook=Boom())
+
+    _, events = _profiled(run, tmp_path)
+    queued = _named(events, "serve.request.queued")
+    assert sorted(e[3]["rid"] for e in queued) == [0, 1, 2]
+
+
+def test_span_helpers_reach_profiler_in_both_modes(tmp_path):
+    """Disabled, `obs.span` is a profiler annotation with its args; with a
+    tracer installed both sinks record the same name."""
+    tr = obs.Tracer()
+
+    def run():
+        with obs.span("obs.off", "t", rid=7):
+            pass
+        with obs.tracing(tr):
+            with obs.span("obs.on", "t"):
+                pass
+        ctx = obs.span_on(None, "obs.reused", "t")
+        for _ in range(3):
+            with ctx:
+                pass
+
+    _, events = _profiled(run, tmp_path)
+    assert [e[3] for e in _named(events, "obs.off")] == [{"rid": 7}]
+    assert len(_named(events, "obs.on")) == 1
+    assert len(_named(events, "obs.reused")) == 3
+    assert [e["name"] for e in tr.events if e.get("ph") == "X"] == ["obs.on"]
 
 
 # ---------------------------------------------------------------------------
