@@ -131,7 +131,7 @@ def check_fused(sched) -> None:
     if sched.state_sharding is not None:
         import jax
         state = jax.device_put(state, sched.state_sharding)
-    text = sched.step.lower(sched.params, state, sched.null,
+    text = sched.step.lower(sched.step_params, state, sched.null,
                             jnp.float32(0.0)).as_text()
     check("tpu_custom_call" in text,
           "the decode step holds no compiled Pallas kernel")
@@ -310,6 +310,9 @@ def one_chip(devs, label: str) -> None:
 
     oracle = Scheduler(cfg, serve_config("ref"), params=sched.params,
                        plan_cache=False)
+    # the same weights, prepared once: a second prepared copy of the MLP
+    # would crowd the chip's memory
+    oracle.step_params = sched.step_params
     sub = [dataclasses.replace(r, arrival=0) for r in reqs[:N_ORACLE]]
     ref = serve(oracle, sub, label, "ref oracle")
     compare(served, ref, "fused vs ref, default precision", enforce=False)
@@ -339,11 +342,11 @@ def step_diff(sched, single, prompt, label: str) -> None:
     n = min(len(prompt), PREFILL_CHUNK)
     tokens = jnp.asarray(np.pad(prompt[:n], (0, PREFILL_CHUNK - n)))[None]
     n_valid = jnp.full((1,), n, jnp.int32)
-    chunk = [s.chunk_fn(s.params, tokens, n_valid, T.init_cache(
+    chunk = [s.chunk_fn(s.step_params, tokens, n_valid, T.init_cache(
         s.cfg, 1, s.scfg.max_len))[0] for s in (sched, single)]
     state = jax.device_put(init_state(sched.cfg, sched.scfg),
                            sched.state_sharding)
-    step = [s.step(s.params, st, s.null, jnp.float32(0.0))[1]["logits"]
+    step = [s.step(s.step_params, st, s.null, jnp.float32(0.0))[1]["logits"]
             for s, st in ((sched, state),
                           (single, init_state(single.cfg, single.scfg)))]
     print(f"{label} sharded vs one device, float32 matmuls, identical "
@@ -371,7 +374,7 @@ def four_chips(devs, label: str) -> None:
 
     # the slots, their caches and the step's outputs sit on four devices
     state = jax.device_put(init_state(sched.cfg, scfg), sched.state_sharding)
-    state, out = sched.step(sched.params, state, sched.null,
+    state, out = sched.step(sched.step_params, state, sched.null,
                             jnp.float32(0.0))
     for leaf in [out["token"], *jax.tree.leaves(state)]:
         if leaf.ndim == 0 or leaf is state.key:

@@ -88,7 +88,8 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
                       key: jax.Array | None = None,
                       var: mrr.StaticVariation | None = None,
                       gate: jax.Array | None = None,
-                      mgate: jax.Array | None = None, *,
+                      mgate: jax.Array | None = None,
+                      w_scale: jax.Array | None = None, *,
                       mapping: Mapping = Mapping.WS,
                       mode: ComputeMode = ComputeMode.MIXED,
                       quant_bits: int = 8, pam_bits: int = 1,
@@ -103,9 +104,11 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
     Semantics are those of the composed `rosa.backends._forward` with the
     "ref" contraction backend (the parity tests pin this); `gate`, `mgate`
     and `var` leaves enter as kernel OPERANDS, so gated evaluators sweep
-    them without retracing.  Contract caveat: the kernel assumes the
-    quantizer's 1e-8 absmax floor never binds on the weights (a weight
-    whose global absmax is below 1e-8 is a degenerate all-zero edge case).
+    them without retracing.  `w_scale`, when given, stands in for
+    `quant.absmax_scale(w)`: a caller that holds `w` fixed computes it
+    once.  Contract caveat: the kernel assumes the quantizer's 1e-8 absmax
+    floor never binds on the weights (a weight whose global absmax is
+    below 1e-8 is a degenerate all-zero edge case).
     The activations are requantized by the composed chain's own code, so
     the kernel contracts the chain's codes; outputs differ from the chain
     only by float accumulation order and the weight realization's float
@@ -156,7 +159,7 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
             noise=noise if realize_x else mrr.IDEAL,
             act_per_vector=act_per_vector)
         xa, s2 = Q.quantize(x_eff, qcfg, per_vector=act_per_vector)
-    sw = Q.absmax_scale(w)
+    sw = Q.absmax_scale(w) if w_scale is None else w_scale
 
     # -- noise/variation offsets for the weights --
     w_off = (_offsets(w, k_w, noise, mrr.expand_lanes(var, w))
@@ -192,7 +195,8 @@ def rosa_fused_matmul(x: jax.Array, w: jax.Array,
         obs.instant("kernels.rosa_fused", "compile", m=m, k=k, n=n,
                     mapping=mapping.name, mode=mode.name,
                     realize_x=realize_x, realize_w=realize_w,
-                    gated=use_gate, mapping_gated=use_mgate)
+                    gated=use_gate, mapping_gated=use_mgate,
+                    w_scale_given=w_scale is not None)
 
     y = rosa_fused_pallas(
         xp, wp, gains, s2, gg, w_off, analog=analog, n_planes=n_planes,

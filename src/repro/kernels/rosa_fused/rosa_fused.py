@@ -227,6 +227,10 @@ def rosa_fused_pallas(xa: jax.Array, w: jax.Array, gains: jax.Array,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # XLA may read `w` straight through its producer, such as one
+            # layer's slice of a stacked weight inside the layer scan,
+            # instead of copying it out first
+            allow_input_fusion=[i == 1 for i in range(len(operands))],
         ),
         interpret=interpret,
     )(*operands)

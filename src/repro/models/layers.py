@@ -375,19 +375,27 @@ def mlp_apply(p: dict, x: jax.Array, engine: "rosa.Engine | None" = None,
     as `step` so layers draw independent noise — the scanned body traces
     once, so the name alone cannot distinguish layers (for the same reason
     an attached EnergyLedger sees the body's two projections once, not L
-    times).  `rosa_cfg` is the legacy spelling (uniform config, no plan)."""
+    times).  `rosa_cfg` is the legacy spelling (uniform config, no plan).
+
+    On the engine path `p` may also come prepared for serving
+    (`repro.serve.prepare_step_params`): `wi` already in its (d, 2f)
+    contraction layout, and `wi_scale`/`wo_scale` holding each weight's
+    full-scale, which then reach the kernel instead of being recomputed."""
     if engine is None and rosa_cfg is not None:
         engine = rosa.Engine.from_config(rosa_cfg)
     if engine is not None and not engine.is_dense:
         if key is not None:
             engine = engine.with_key(key)
         b, s, d = x.shape
-        f = p["wi"].shape[-1]
-        gu = engine.matmul(x.reshape(-1, d), p["wi"].reshape(d, 2 * f),
-                           name=f"{name}/wi", step=step).reshape(b, s, 2, f)
+        wi, f = p["wi"], p["wo"].shape[0]
+        if wi.ndim == 3:                  # the model's (d, 2, f) layout
+            wi = wi.reshape(d, 2 * f)
+        gu = engine.matmul(x.reshape(-1, d), wi, name=f"{name}/wi",
+                           step=step, w_scale=p.get("wi_scale")
+                           ).reshape(b, s, 2, f)
         h = jax.nn.silu(gu[..., 0, :]) * gu[..., 1, :]
         y = engine.matmul(h.reshape(-1, f), p["wo"], name=f"{name}/wo",
-                          step=step)
+                          step=step, w_scale=p.get("wo_scale"))
         return y.reshape(b, s, d).astype(x.dtype)
     gu = jnp.einsum("bsd,dcf->bscf", x, p["wi"])
     h = jax.nn.silu(gu[..., 0, :]) * gu[..., 1, :]

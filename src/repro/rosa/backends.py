@@ -150,17 +150,18 @@ def _pallas_backend(x: jax.Array, w: jax.Array, cfg: RosaConfig) -> jax.Array:
 
 @register_backend("fused", raw=True)
 def _fused_backend(x: jax.Array, w: jax.Array, cfg: RosaConfig, *,
-                   key=None, var=None, gate=None, mgate=None) -> jax.Array:
+                   key=None, var=None, gate=None, mgate=None,
+                   w_scale=None) -> jax.Array:
     # deferred import: pulls in jax.experimental.pallas only when routed here
     from repro.kernels.rosa_fused import ops as fused_ops
     # decomposition radix follows osa_cfg (what the composed ref chain
     # uses), NOT RosaConfig.pam_bits (which only the per-op pallas backend
     # reads) — the fused path must price and compute like the chain it fuses
     return fused_ops.rosa_fused_matmul(
-        x, w, key, var, gate, mgate, mapping=cfg.mapping, mode=cfg.mode,
-        quant_bits=cfg.quant_bits, pam_bits=cfg.osa_cfg.pam_bits,
-        act_per_vector=cfg.act_per_vector, noise=cfg.noise,
-        osa_cfg=cfg.osa_cfg, p=cfg.mrr_params)
+        x, w, key, var, gate, mgate, w_scale, mapping=cfg.mapping,
+        mode=cfg.mode, quant_bits=cfg.quant_bits,
+        pam_bits=cfg.osa_cfg.pam_bits, act_per_vector=cfg.act_per_vector,
+        noise=cfg.noise, osa_cfg=cfg.osa_cfg, p=cfg.mrr_params)
 
 
 # ---------------------------------------------------------------------------
@@ -260,7 +261,10 @@ def _forward(x: jax.Array, w: jax.Array, cfg: RosaConfig,
              key: jax.Array | None,
              var: mrr.StaticVariation | None = None,
              gate: jax.Array | None = None,
-             mgate: jax.Array | None = None) -> jax.Array:
+             mgate: jax.Array | None = None,
+             w_scale: jax.Array | None = None) -> jax.Array:
+    # `w_scale` (the weight's precomputed full-scale) reaches raw backends
+    # only: the composed chain computes the same value itself
     if cfg.mode is ComputeMode.MIXED:
         if cfg.noise.is_ideal and cfg.osa_cfg.is_ideal \
                 and cfg.backend in ("auto", "dense") \
@@ -278,7 +282,7 @@ def _forward(x: jax.Array, w: jax.Array, cfg: RosaConfig,
         if bname in _RAW_BACKENDS:
             # fully-fused pipeline: conditioning happens inside the kernel
             return contract(x, w, cfg, key=key, var=var, gate=gate,
-                            mgate=mgate)
+                            mgate=mgate, w_scale=w_scale)
         if mgate is not None:
             # mapping superposition: realize BOTH orientations and blend the
             # OPERANDS by the traced selector (exact for mgate in {0, 1}) —
@@ -306,7 +310,7 @@ def _forward(x: jax.Array, w: jax.Array, cfg: RosaConfig,
             # single-shot analog readout, fused end to end (mgate is
             # ignored in ANALOG mode, matching the composed branch below)
             return contract(x, w, cfg, key=key, var=var, gate=gate,
-                            mgate=None)
+                            mgate=None, w_scale=w_scale)
         if key is not None:
             k_w, k_x = jax.random.split(key)
         else:
@@ -327,23 +331,28 @@ def rosa_matmul(x: jax.Array, w: jax.Array, cfg: RosaConfig = DEFAULT,
                 key: jax.Array | None = None,
                 var: mrr.StaticVariation | None = None,
                 gate: jax.Array | None = None,
-                mgate: jax.Array | None = None) -> jax.Array:
+                mgate: jax.Array | None = None,
+                w_scale: jax.Array | None = None) -> jax.Array:
     """Optical matmul  y = x @ w  through the configured ROSA pipeline.
 
     x: (..., K) activations; w: (K, N) weights; returns (..., N).
     `var` pins one chip's static device variation on the analog operand;
     `gate` (traced scalar in [0, 1]) blends the analog path against the
     exact digital one; `mgate` (traced, {0=WS, 1=IS}) superposes the two
-    mapping orientations.  Straight-through gradients w.r.t. both x and w
-    (noise, variation and gates are treated as non-differentiable).
+    mapping orientations.  `w_scale`, when given, is `w`'s per-tensor
+    full-scale (`quant.absmax_scale(w)`), precomputed by a caller that
+    holds `w` fixed across calls.  Straight-through gradients w.r.t. both
+    x and w (noise, variation, gates and `w_scale` are treated as
+    non-differentiable).
     """
     lead = x.shape[:-1]
-    y = _forward(x.reshape(-1, x.shape[-1]), w, cfg, key, var, gate, mgate)
+    y = _forward(x.reshape(-1, x.shape[-1]), w, cfg, key, var, gate, mgate,
+                 w_scale)
     return y.reshape(*lead, w.shape[-1])
 
 
-def _fwd(x, w, cfg, key, var, gate, mgate):
-    return rosa_matmul(x, w, cfg, key, var, gate, mgate), (x, w)
+def _fwd(x, w, cfg, key, var, gate, mgate, w_scale):
+    return rosa_matmul(x, w, cfg, key, var, gate, mgate, w_scale), (x, w)
 
 
 def _bwd(cfg, res, g):
@@ -352,7 +361,7 @@ def _bwd(cfg, res, g):
     x2 = x.reshape(-1, x.shape[-1])
     dx = (g2 @ w.T).reshape(x.shape)
     dw = x2.T @ g2
-    return dx, dw, None, None, None, None
+    return dx, dw, None, None, None, None, None
 
 
 rosa_matmul.defvjp(_fwd, _bwd)

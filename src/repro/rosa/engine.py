@@ -236,12 +236,15 @@ class Engine:
     # -- the routed matmul --------------------------------------------------
     def matmul(self, x: jax.Array, w: jax.Array, *, name: str = "",
                step: int | jax.Array = 0,
-               key: jax.Array | None = None) -> jax.Array:
+               key: jax.Array | None = None,
+               w_scale: jax.Array | None = None) -> jax.Array:
         """Compute y = x @ w through this layer's resolved config.
 
         x: (..., K); w: (K, N).  An explicit `key` overrides the engine's
-        folded per-layer key.  Dense layers (resolved config None) contract
-        exactly in the caller's dtype.
+        folded per-layer key.  `w_scale` is `w`'s precomputed full-scale
+        (`quant.absmax_scale(w)`), for weights that stay fixed across
+        calls.  Dense layers (resolved config None) contract exactly in
+        the caller's dtype.
         """
         cfg = self.plan.resolve(name)
         if obs.enabled():
@@ -268,7 +271,8 @@ class Engine:
             key = self.key_for(name, step)
         return rosa_matmul(x.astype(jnp.float32), w.astype(jnp.float32),
                            cfg, key, self.variation_for(name),
-                           self.gate_for(name), self.mapping_gate_for(name))
+                           self.gate_for(name), self.mapping_gate_for(name),
+                           w_scale)
 
     def effective_weight(self, w: jax.Array, *, name: str = "",
                          step: int | jax.Array = 0,

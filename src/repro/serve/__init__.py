@@ -32,7 +32,8 @@ from repro.serve.config import ServeConfig, serving_model_config
 from repro.serve.decode import (DecodeState, PrefillTask, init_state,
                                 make_admit, make_admit_step, make_chunk_fn,
                                 make_evict, make_prefill_fn, make_serve_step,
-                                null_admit, sample_token)
+                                null_admit, prepare_step_params,
+                                sample_token)
 from repro.serve.loadgen import poisson_requests
 from repro.serve.metrics import (build_serving_engine, energy_metrics,
                                  report_metrics, smoke_report)
@@ -45,6 +46,7 @@ __all__ = [
     "Scheduler", "ServeConfig", "ServeReport", "TickHook",
     "build_serving_engine", "energy_metrics", "init_state", "make_admit",
     "make_admit_step", "make_chunk_fn", "make_evict", "make_prefill_fn",
-    "make_serve_step", "null_admit", "poisson_requests", "report_metrics",
+    "make_serve_step", "null_admit", "poisson_requests",
+    "prepare_step_params", "report_metrics",
     "run_sequential", "sample_token", "serving_model_config", "smoke_report",
 ]

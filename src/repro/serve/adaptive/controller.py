@@ -315,7 +315,7 @@ class AdaptiveController(DriftMonitor):
             new_step = make_drift_step(bundle, scfg, new_prog)
             dummy = jax.tree.map(jnp.zeros_like, state)
             with _ledger_scope(new_prog.engine, "decode"):
-                warm_out = new_step(sched.params, dummy, sched.null,
+                warm_out = new_step(sched.step_params, dummy, sched.null,
                                     jnp.float32(scfg.temperature),
                                     jnp.float32(resid))
             jax.block_until_ready(warm_out[0].tok)

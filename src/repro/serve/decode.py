@@ -29,9 +29,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import quant as Q
 from repro.models import transformer as T
 from repro.models.model import (ModelBundle, cache_axes, evict_slot,
                                 pad_cache, write_slot)
+from repro.obs import trace as obs
 from repro.serve.config import ServeConfig
 
 
@@ -78,6 +80,46 @@ def make_admit(req_cache, slot: int, rid: int, token, budget: int) -> dict:
             "token": jnp.reshape(jnp.asarray(token, jnp.int32), (1,)),
             "rid": jnp.full((1,), rid, jnp.int32),
             "budget": jnp.full((1,), budget, jnp.int32)}
+
+
+# ---------------------------------------------------------------------------
+# Step params: the served weights in the form the optical steps consume
+# ---------------------------------------------------------------------------
+@jax.jit
+def _prepare_mlp(wi: jax.Array, wo: jax.Array):
+    """Stacked (L, d, 2, f) gate|up and (L, f, d) down projections -> the
+    (L, d, 2f) contraction layout of `wi` and each layer's full-scale of
+    both, by `quant.absmax_scale` (the value a step would compute)."""
+    scale = jax.vmap(lambda w: Q.absmax_scale(w.astype(jnp.float32)))
+    return wi.reshape(*wi.shape[:2], -1), scale(wi), scale(wo)
+
+
+def prepare_step_params(cfg: T.ModelConfig, params):
+    """The step params: `params` with its routed MLP weights put once into
+    the form the optical engine consumes in every step.
+
+    With the optical MLP on (`cfg.rosa_mlp`, dense FFN), the stacked
+    `layers/ffn/wi` becomes its (L, d, 2f) contraction layout, and
+    `wi_scale`/`wo_scale` (L,) hold each layer's weight full-scale, so no
+    step re-lays out gate|up or re-reduces a weight (`layers.mlp_apply`
+    takes either form).  Values are moved or reduced exactly, so the steps
+    compute the same numbers.  Every other leaf is the same array object;
+    `params` itself is left as it is.  Otherwise `params` is returned."""
+    ffn = params.get("layers", {}).get("ffn")
+    if not (cfg.rosa_mlp and cfg.moe is None and ffn is not None):
+        return params
+    wi, wo = ffn["wi"], ffn["wo"]
+    n_layers = wi.shape[0]
+    with obs.span("serve.prepare_weights", "serve", n_weights=2 * n_layers,
+                  relaid_bytes=int(np.prod(wi.shape)) * wi.dtype.itemsize):
+        if isinstance(wi, jax.ShapeDtypeStruct):    # shapes only: lowering
+            new = jax.eval_shape(_prepare_mlp, wi, wo)
+        else:
+            new = jax.block_until_ready(_prepare_mlp(wi, wo))
+    wi2, wi_scale, wo_scale = new
+    layers = dict(params["layers"],
+                  ffn=dict(ffn, wi=wi2, wi_scale=wi_scale, wo_scale=wo_scale))
+    return dict(params, layers=layers)
 
 
 # ---------------------------------------------------------------------------

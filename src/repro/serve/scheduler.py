@@ -42,7 +42,8 @@ from repro.serve.config import ServeConfig, serving_model_config
 from repro.serve.decode import (PrefillTask, init_state, make_admit,
                                 make_admit_step, make_chunk_fn, make_evict,
                                 make_prefill_fn, make_serve_step, null_admit,
-                                sample_token, slot_state_specs)
+                                prepare_step_params, sample_token,
+                                slot_state_specs)
 
 
 @dataclasses.dataclass
@@ -211,7 +212,12 @@ class Scheduler:
     into ONE `rosa.Program` (hybrid plan autotuned on the decode trace,
     disk plan cache, pinned chip, energy ledger) and every jitted step —
     decode, admit, prefill chunk, whole prefill, evict — is built from it,
-    so the frozen engine reaches each trace without a global stack."""
+    so the frozen engine reaches each trace without a global stack.
+
+    `params` keeps the weights as given, in the model's layout; the steps
+    take `step_params`, made from them once whenever `params` is set
+    (`prepare_step_params`: the routed MLP weights in the form the
+    optical engine consumes)."""
 
     def __init__(self, model_cfg, scfg: ServeConfig, params=None,
                  init_seed: int = 0, mesh=None, engine=None,
@@ -230,16 +236,19 @@ class Scheduler:
             self.engine = self.program.engine
         elif engine is not None:
             self.program = serving_program(self.bundle, scfg, engine)
-        # with a mesh, params are replicated on every device and the slot
-        # state is sharded over it up front, so no step reshards them
-        self.state_sharding = None
+        # with a mesh, params (and so the step params) are replicated on
+        # every device and the slot state is sharded over it up front, so
+        # no step reshards them
+        self.state_sharding = self._everywhere = None
         with self._engine_ctx():
             if mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
-                everywhere = NamedSharding(mesh, P())
+                self._everywhere = NamedSharding(mesh, P())
                 self.params = (
-                    jax.device_put(params, everywhere) if params is not None
-                    else jax.jit(self.bundle.init, out_shardings=everywhere)(
+                    jax.device_put(params, self._everywhere)
+                    if params is not None
+                    else jax.jit(self.bundle.init,
+                                 out_shardings=self._everywhere)(
                         jax.random.PRNGKey(init_seed)))
                 self.state_sharding = jax.tree.map(
                     lambda s: NamedSharding(mesh, s),
@@ -261,6 +270,21 @@ class Scheduler:
         self.null = null_admit(self.cfg, scfg)
         self.sample1 = jax.jit(sample_token)
         self.base_key = jax.random.PRNGKey(scfg.seed)
+
+    @property
+    def params(self):
+        """The served weights, in the model's own layout."""
+        return self._params
+
+    @params.setter
+    def params(self, params) -> None:
+        self._params = step = params
+        if params is not None and self.engine is not None \
+                and not self.engine.is_dense:
+            step = prepare_step_params(self.cfg, params)
+            if step is not params and self._everywhere is not None:
+                step = jax.device_put(step, self._everywhere)
+        self.step_params = step
 
     def _engine_ctx(self):
         """Ambient context for the few non-jitted call sites (param init);
@@ -384,7 +408,7 @@ class Scheduler:
                     if inflight is not None:
                         req, task = inflight
                         with prefill_ctx, self._scope("prefill"):
-                            task.advance(self.params)
+                            task.advance(self.step_params)
                         if etrack is not None:
                             etrack.tick("prefill")
                         rep.prefill_chunks += 1
@@ -452,7 +476,7 @@ class Scheduler:
                         extra = hook.step_args(tick) if hook is not None \
                             else ()
                         with decode_ctx, self._scope("decode"):
-                            state, out = self.step(self.params, state,
+                            state, out = self.step(self.step_params, state,
                                                    admit, temp, *extra)
                         if etrack is not None:
                             etrack.tick("decode")

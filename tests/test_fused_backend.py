@@ -13,7 +13,7 @@ import pytest
 
 from repro import rosa
 from repro.configs import get_smoke
-from repro.core import mrr
+from repro.core import mrr, quant
 from repro.core.constants import ROSA_OPTIMAL, ComputeMode, Mapping
 from repro.serve import (Request, Scheduler, ServeConfig, run_sequential)
 
@@ -69,18 +69,33 @@ def _assert_quantized_parity(y, y_ref, *, qmax: int = 127,
 
 
 def _assert_dispatch_parity(cfg: rosa.RosaConfig, seed: int, *,
-                            key=True, var=True, gate=None, mgate=None):
+                            key=True, var=True, gate=None, mgate=None,
+                            w_scale=False):
+    """With `w_scale`, both backends also get the weight's precomputed
+    full-scale: the fused result must not move by a bit, and the composed
+    chain ignores it."""
     x, w, kn = _operands(seed)
     var_ = _var(x.shape[1]) if var else None
     kn_ = kn if key else None
     args = (kn_, var_, gate, mgate)
-    y_f = rosa.rosa_matmul(x, w, dataclasses.replace(cfg, backend="fused"),
-                           *args)
+    fused = dataclasses.replace(cfg, backend="fused")
+    y_f = rosa.rosa_matmul(x, w, fused, *args)
     y_r = rosa.rosa_matmul(x, w, dataclasses.replace(cfg, backend="ref"),
                            *args)
     _assert_quantized_parity(y_f, y_r)
+    if w_scale:
+        sw = quant.absmax_scale(w)
+        np.testing.assert_array_equal(
+            np.asarray(rosa.rosa_matmul(x, w, fused, *args, sw)),
+            np.asarray(y_f))
+        np.testing.assert_array_equal(
+            np.asarray(rosa.rosa_matmul(
+                x, w, dataclasses.replace(cfg, backend="ref"), *args, sw)),
+            np.asarray(y_r))
 
 
+@pytest.mark.parametrize("w_scale", [False, True],
+                         ids=["scale_computed", "scale_given"])
 @pytest.mark.parametrize("seed,cfg_kw,call_kw", [
     (0, {}, {}),                                              # noisy WS
     (1, {"mapping": Mapping.IS, "act_per_vector": True}, {}),
@@ -89,9 +104,22 @@ def _assert_dispatch_parity(cfg: rosa.RosaConfig, seed: int, *,
     (4, {"mode": ComputeMode.ANALOG}, {"gate": 0.7}),
     (5, {"noise": mrr.IDEAL}, {"var": False}),                # ideal path
 ], ids=["ws", "is_apv", "gated", "mgated", "analog", "ideal"])
-def test_fused_dispatch_matches_ref(seed, cfg_kw, call_kw):
+def test_fused_dispatch_matches_ref(seed, cfg_kw, call_kw, w_scale):
     _assert_dispatch_parity(dataclasses.replace(NOISY, **cfg_kw), seed,
-                            **call_kw)
+                            w_scale=w_scale, **call_kw)
+
+
+@pytest.mark.parametrize("mapping", [Mapping.WS, Mapping.IS])
+def test_fused_kernel_takes_given_weight_scale(mapping):
+    """`rosa_fused_matmul(..., w_scale=absmax_scale(w))` is the call
+    without it, bit for bit, on the kernel's own entry point."""
+    from repro.kernels.rosa_fused.ops import rosa_fused_matmul
+    x, w, kn = _operands(10)
+    kw = dict(mapping=mapping, noise=mrr.PAPER_NOISE, act_per_vector=True)
+    y = rosa_fused_matmul(x, w, kn, _var(x.shape[1]), **kw)
+    y_s = rosa_fused_matmul(x, w, kn, _var(x.shape[1]),
+                            w_scale=quant.absmax_scale(w), **kw)
+    np.testing.assert_array_equal(np.asarray(y_s), np.asarray(y))
 
 
 def test_fused_nonideal_osa_dispatch(key):
@@ -116,19 +144,33 @@ def test_fused_batched_leading_dims(key):
     _assert_quantized_parity(y_f, y_r)
 
 
-def test_fused_straight_through_gradients(key):
+@pytest.mark.parametrize("w_scale", [False, True],
+                         ids=["scale_computed", "scale_given"])
+def test_fused_straight_through_gradients(key, w_scale):
     """The custom_vjp is backend-agnostic: fused forward, exact dense
-    backward (identical cotangents to the ref backend)."""
+    backward (identical cotangents to the ref backend).  A given weight
+    full-scale is non-differentiable and leaves the gradients as they
+    are, bit for bit."""
     x, w, kn = _operands(7, m=6, k=32, n=8)
 
-    def loss(backend):
+    def loss(backend, given):
         cfg = dataclasses.replace(NOISY, backend=backend)
-        return lambda x_, w_: jnp.sum(rosa.rosa_matmul(x_, w_, cfg, kn) ** 2)
 
-    gx_f, gw_f = jax.grad(loss("fused"), argnums=(0, 1))(x, w)
-    gx_r, gw_r = jax.grad(loss("ref"), argnums=(0, 1))(x, w)
+        def f(x_, w_):
+            sw = quant.absmax_scale(w_) if given else None
+            return jnp.sum(rosa.rosa_matmul(x_, w_, cfg, kn, None, None,
+                                            None, sw) ** 2)
+        return f
+
+    gx_f, gw_f = jax.grad(loss("fused", w_scale), argnums=(0, 1))(x, w)
+    gx_r, gw_r = jax.grad(loss("ref", w_scale), argnums=(0, 1))(x, w)
     _assert_quantized_parity(gx_f, gx_r)
     _assert_quantized_parity(gw_f, gw_r)
+    if w_scale:
+        for got, want in zip(
+                (gx_f, gw_f),
+                jax.grad(loss("fused", False), argnums=(0, 1))(x, w)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
