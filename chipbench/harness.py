@@ -15,6 +15,9 @@ window ends within `--seconds` unless its first slice alone is longer.
 Every end-to-end metric covers every request and all the time of every
 slice.  With `--trace 1` the same window runs under the JAX profiler and
 the line carries the per-layer metrics instead of the end-to-end ones.
+Readers get the trace, the window's `ServeReport` counts and the program's
+own counters over the window (`ctx.program_counters`), so a counter the
+program adds reaches its reader with no edit here.
 
 Standard output ends with one JSON line; the numbers compared for
 `correct`, each beside its limit, are the last lines of standard error and
@@ -179,6 +182,54 @@ class Window:
                 "slices": len(reps), "requests": len(self.reqs)}
 
 
+def read_counters(reg) -> dict:
+    """The additive readings of a `repro.obs` metrics registry: each
+    Counter's value, and each Histogram's count and sum as `<name>.count`
+    and `<name>.sum`.  Gauges are not additive and are left out."""
+    from repro.obs.metrics import Counter, Histogram
+    out = {}
+    for name, item in reg.items().items():
+        if isinstance(item, Counter):
+            out[name] = item.value
+        elif isinstance(item, Histogram):
+            snap = item.snapshot()
+            out[f"{name}.count"] = snap["count"]
+            out[f"{name}.sum"] = snap["sum"]
+    return out
+
+
+def counters_over(before: dict, after: dict) -> dict:
+    """What was counted between two `read_counters` readings; a counter
+    first created in between counts from 0."""
+    return {k: v - before.get(k, 0) for k, v in after.items()}
+
+
+def run_window(win: Window, seconds: float, logdir: Path | None) -> dict:
+    """Runs the window, under the JAX profiler where `logdir` is given, and
+    returns the program's counters over it.  The process-global registry
+    is read outside the profiler session and outside the window's clock,
+    so the warm-up and anything run earlier in the process fall outside
+    the difference, and neither the window nor its trace sees the reading."""
+    import jax
+
+    from chipbench import trace
+    from repro.obs.metrics import registry
+    before = read_counters(registry())
+    if logdir is not None:
+        shutil.rmtree(logdir, ignore_errors=True)
+        # host TraceMe events (dispatches, transfers) label the idle gaps;
+        # the Python function tracer would slow the host loop it measures
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(logdir), profiler_options=opts)
+        with jax.profiler.TraceAnnotation(trace.WINDOW):
+            win.run(seconds)
+        jax.profiler.stop_trace()
+    else:
+        win.run(seconds)
+    return counters_over(before, read_counters(registry()))
+
+
 def end_to_end(win: Window, setup_s: float) -> dict:
     done = win.done()
     c = win.counts()
@@ -283,7 +334,6 @@ def main(argv=None, t0: float | None = None) -> dict:
     cfg, mix, cell = c["cfg"], c["mix"], c["cell"]
     set_matmul_precision(cfg)
     devs, peak = find_chip(cell["chips"])
-    import jax
 
     from chipbench import trace
     setup_s, served, plan = setup(c, args.seed, t0)
@@ -293,22 +343,12 @@ def main(argv=None, t0: float | None = None) -> dict:
 
     win = Window(served, mix, args.seed, cfg["vocab"])
     compiles = CompileCount()
-    if args.trace:
-        logdir = STATE / "trace" / cell["name"]
-        shutil.rmtree(logdir, ignore_errors=True)
-        # host TraceMe events (dispatches, transfers) label the idle gaps;
-        # the Python function tracer would slow the host loop it measures
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(str(logdir), profiler_options=opts)
-        with jax.profiler.TraceAnnotation(trace.WINDOW):
-            win.run(args.seconds)
-        jax.profiler.stop_trace()
-    else:
-        win.run(args.seconds)
+    logdir = STATE / "trace" / cell["name"] if args.trace else None
+    program_counters = run_window(win, args.seconds, logdir)
     counts = win.counts()
     log(f"window: {win.seconds:.3f} s, drain share {win.drain_share():.4f}, "
         f"{counts}, programs compiled or loaded inside it: {compiles.n}")
+    log(f"program counters over the window: {program_counters}")
     stats = devs[0].memory_stats() or {}
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs),
@@ -320,7 +360,8 @@ def main(argv=None, t0: float | None = None) -> dict:
         path = trace.find_xplane(str(logdir))
         red = trace.load_xplane(path)
         ctx = types.SimpleNamespace(
-            trace=red, counts=counts, cfg=cfg, mix=mix, peak=peak,
+            trace=red, counts=counts, program_counters=program_counters,
+            cfg=cfg, mix=mix, peak=peak,
             gemms=served.gemms(), n_slots=mix["serve"]["n_slots"],
             prefill_chunk=mix["serve"]["prefill_chunk"],
             requests=[(len(r["prompt"]), len(r["tokens"]))

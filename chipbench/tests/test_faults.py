@@ -43,7 +43,7 @@ def run(seed: int = 2**31 + 11) -> dict:
     harness.set_matmul_precision(c["cfg"])
     setup_s, served, plan = harness.setup(c, seed, time.perf_counter())
     win = harness.Window(served, c["mix"], seed, c["cfg"]["vocab"])
-    win.run(0.5)
+    harness.run_window(win, 0.5, None)
     e2e = harness.end_to_end(win, setup_s)
     metrics = {m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
                for m in c["end_to_end"]}

@@ -2,8 +2,10 @@
 
 Dense files build the same program config as they always have; an
 `mla_moe` file (MLA attention, routed and shared experts, one leading
-dense layer) is served end to end at smoke sizes on the CPU; and each
-file the serving path cannot take is refused with the reason.
+dense layer) is served end to end at smoke sizes on the CPU; a `moe` file
+builds its block values; and each file the serving path cannot take is
+refused with the reason.  Which families route their GEMMs through the
+optical engine is the program's to change, so no test here depends on it.
 """
 
 import copy
@@ -14,6 +16,7 @@ from pathlib import Path
 import pytest
 
 from chipbench import harness, sut, traffic, weights
+from chipbench.tests.test_faults import tiny_cell
 
 ROOT = Path(__file__).resolve().parents[2]
 DATA = Path(__file__).resolve().parent / "data"
@@ -83,8 +86,11 @@ def test_mla_moe_file_is_served_end_to_end():
         for k, v in cfg[block].items():
             assert getattr(getattr(mc, block), k) == v, (block, k)
     served = sut.Served(cfg, mix, seeded(cfg))
-    assert served.plan() == cfg["optical"]["mapping"]
-    assert served.gemms() == [("mlp/wi", 64, 256), ("mlp/wo", 128, 64)]
+    # the GEMMs the file maps are planned as it states; whether the experts
+    # route through the optical engine as well is the program's to change
+    want = cfg["optical"]["mapping"]
+    plan = served.plan()
+    assert {k: plan.get(k) for k in want} == want
     served.warm()
     reqs = traffic.make_slice(mix, 2**31 + 7, 0, cfg["vocab"])
     rep = served.run(reqs)
@@ -158,11 +164,20 @@ def test_file_is_refused_with_its_reason(fault):
         sut.model_config(cfg)
 
 
-def test_moe_file_with_no_optical_gemm_is_refused():
-    harness.prepare_env()
+def test_moe_file_builds_its_block_values():
     cfg = moe_file()
-    assert sut.model_config(cfg).moe.n_experts == 8
+    mc = sut.model_config(cfg)
+    assert mc.family == "moe" and mc.moe.n_experts == 8
+    for k, v in cfg["moe"].items():
+        assert getattr(mc.moe, k) == v, k
+
+
+def test_program_with_no_optical_gemm_is_refused(monkeypatch):
+    """A program whose optical trace is empty would have its cell time
+    digital matmuls only."""
+    harness.prepare_env()
+    cfg = tiny_cell()["cfg"]
+    monkeypatch.setattr(sut.Served, "gemms", lambda self: [])
     with pytest.raises(ValueError, match="routes no GEMM through the "
                        "optical engine"):
         sut.Served(cfg, tiny_mix(), seeded(cfg))
-
